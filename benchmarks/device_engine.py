@@ -1610,6 +1610,9 @@ def main(quick: bool = False, out: str | None = None, repeats: int = 5):
 if __name__ == "__main__":
     import argparse
 
+    from repro.compile_cache import use_compile_cache
+
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--quick", action="store_true")
     ap.add_argument("--near-full-only", action="store_true",

@@ -19,6 +19,9 @@ def main() -> None:
     p.add_argument("--only", default=None)
     args = p.parse_args()
 
+    from repro.compile_cache import use_compile_cache
+
+    use_compile_cache()
     from benchmarks import (
         batch_counts,
         compile_times,

@@ -23,6 +23,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.api import ARG_WIDTH, Config, SimProgram
+from repro.compile_cache import use_compile_cache
 
 ARRIVE, DEPART, TALLY = 0, 1, 2  # registration-order type ids
 C_SERVERS = 2
@@ -154,6 +155,7 @@ def make_program():
 
 
 def main():
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--stations", type=int, default=4)
     ap.add_argument("--t-open", type=float, default=30.0)

@@ -30,6 +30,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.api import ARG_WIDTH, Config, SimProgram
+from repro.compile_cache import use_compile_cache
 
 HOP = 0  # single-type alphabet: registration order id
 
@@ -58,8 +59,16 @@ def _mix(t, src):
 
 
 def build_program(num_lps: int = 8, t_stop: float = 40.0,
-                  max_batch_len: int = 4, capacity: int = 256) -> SimProgram:
-    """The PHOLD model: one emitting HOP type, one initial hop per LP."""
+                  max_batch_len: int = 4, capacity: int = 256,
+                  msgs_per_lp: int = 1, seed: int | None = None
+                  ) -> SimProgram:
+    """The PHOLD model: one emitting HOP type.
+
+    Without ``seed`` the population is one hop per LP at ``0.5 * lp``.
+    With it, each LP starts ``msgs_per_lp`` hops at times drawn from
+    the 0.5 grid in ``[0, 8)`` — PHOLD's closed population, which every
+    executed hop keeps constant until ``t_stop``.
+    """
     prog = SimProgram(
         "phold",
         config=Config(max_batch_len=max_batch_len, capacity=capacity,
@@ -83,8 +92,15 @@ def build_program(num_lps: int = 8, t_stop: float = 40.0,
                     .at[0, 2].set(dst.astype(jnp.float32)))
         return {"counts": counts, "checksum": checksum}, emit
 
+    if seed is None:
+        for lp in range(num_lps):
+            prog.schedule(0.5 * lp, "HOP", arg=[float(lp)])
+        return prog
+    rng = np.random.default_rng(seed)
+    times = 0.5 * rng.integers(0, 16, size=(num_lps, msgs_per_lp))
     for lp in range(num_lps):
-        prog.schedule(0.5 * lp, "HOP", arg=[float(lp)])
+        for t in times[lp]:
+            prog.schedule(float(t), "HOP", arg=[float(lp)])
     return prog
 
 
@@ -104,6 +120,7 @@ def make_program():
 
 
 def main():
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--lps", type=int, default=8)
     ap.add_argument("--t-stop", type=float, default=40.0)

@@ -15,6 +15,7 @@ import jax
 import numpy as np
 
 from repro import poc
+from repro.compile_cache import use_compile_cache
 from repro.core import compose_word_fn
 
 ITERS = 300_000
@@ -22,6 +23,7 @@ EVENTS = 200
 
 
 def main():
+    use_compile_cache()
     # 1. The event alphabet: Increment (heavy loop) and Set (constant),
     #    declared once on a SimProgram.
     prog = poc.build_program(iters=ITERS)

@@ -10,12 +10,14 @@ the lookahead window execute as pre-composed fused k-step programs.
 import jax
 import numpy as np
 
+from repro.compile_cache import use_compile_cache
 from repro.configs import get_config
 from repro.models import LM
 from repro.serving.engine import ServingEngine
 
 
 def main():
+    use_compile_cache()
     cfg = get_config("phi4-mini-3.8b").reduced()
     model = LM(cfg)
     params = model.init(jax.random.PRNGKey(0))

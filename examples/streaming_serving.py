@@ -24,6 +24,7 @@ import time
 
 import numpy as np
 
+from repro.compile_cache import use_compile_cache
 from repro.core.program import Config
 from repro.serving.scenarios import (
     build_open_admission_program,
@@ -33,6 +34,7 @@ from repro.stream import PoissonSource, TraceReader, source_events
 
 
 def main():
+    use_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--tiny", action="store_true",
                     help="small sizes for CI smoke")

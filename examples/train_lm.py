@@ -14,6 +14,7 @@ import dataclasses
 import jax
 
 from repro.checkpoint.manager import CheckpointManager
+from repro.compile_cache import use_compile_cache
 from repro.configs import get_config
 from repro.data.pipeline import DataConfig, make_batch
 from repro.models import LM
@@ -32,6 +33,7 @@ def config_100m():
 
 
 def main(argv=None):
+    use_compile_cache()
     p = argparse.ArgumentParser()
     p.add_argument("--steps", type=int, default=300)
     p.add_argument("--batch", type=int, default=8)

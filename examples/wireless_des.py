@@ -28,6 +28,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.api import Config, SimProgram
+from repro.compile_cache import use_compile_cache
 from repro.core import compose_word_fn
 
 N_RECEIVERS = 4
@@ -85,6 +86,7 @@ def make_program():
 
 
 def main():
+    use_compile_cache()
     prog = build_program()
 
     # cross-event DCE check: [SleepAll, Broadcast, WakeAll] -> no one can

@@ -16,7 +16,7 @@ is a known constant.  Booleans are 0/1 intervals.  Interpretation
 rules:
 
 * If every input of an equation is known, the primitive is *executed*
-  (``prim.bind``) — exact constant folding, including whole ``pjit``
+  (``prim.bind``) — exact constant folding, including whole ``jit``
   sub-jaxprs, ``iota``, scatters of constants, even ``while`` loops
   over constants.
 * Otherwise a per-primitive transfer function propagates intervals.
@@ -303,7 +303,7 @@ def _scatter_points(eqn, op: Ival, idx: Ival, upd: Ival, *, add: bool):
 # ---------------------------------------------------------------------------
 
 _SUBJAXPR_PRIMS = {
-    "pjit", "closed_call", "core_call", "remat", "checkpoint",
+    "jit", "closed_call", "core_call", "remat", "checkpoint",
     "custom_jvp_call", "custom_vjp_call", "custom_jvp_call_jaxpr",
 }
 
@@ -374,7 +374,7 @@ def _rule(eqn, ins: list[Ival]):  # noqa: C901 - one big transfer table
         sub = _sub_jaxpr(eqn)
         if sub is None:
             return None
-        # jnp.remainder lowers to pjit[name=remainder]; the generic
+        # jnp.remainder lowers to jit[name=remainder]; the generic
         # recursion loses the sign correction (select over an unknown
         # predicate), so apply the Python-mod rule directly — it is
         # exact for positive divisors regardless of the dividend.

@@ -245,7 +245,7 @@ class DeviceEngine:
     implementation: ``"xla"`` (default — the all-pairs-rank + gather
     shapes tuned for XLA:CPU) or ``"pallas"`` (Pallas kernels in
     ``repro.kernels.queue_front`` keeping the window extract and the
-    front counting-merge in VMEM; interpret mode off-TPU, bit-identical
+    front counting-merge in VMEM; interpret mode on CPU, bit-identical
     output, requires ``queue_mode="tiered3"``).
 
     ``entity_handlers`` maps a type_id to an entity-local handler

@@ -522,7 +522,7 @@ class SimProgram:
         static analyzer (DESIGN.md §11; needs
         :meth:`example_state`).
         ``queue_kernels="pallas"`` swaps the tiered3 front-tier hot
-        loops for the Pallas kernels (interpret mode off-TPU).
+        loops for the Pallas kernels (interpret mode on CPU).
         ``validate`` arms the on-device invariant auditor (DESIGN.md
         §9): ``"cheap"`` folds per-super-step fault bits into the
         loop carry (CI-gated at <=1.10x the ``"off"`` cost),

@@ -1112,7 +1112,7 @@ def _tiered_fill_finish(q, rows, b_time, seq_r, insert, counters,
     ``kernels="pallas"`` computes the front counting-merge with the
     Pallas kernel (:func:`repro.kernels.queue_front.front_merge`) —
     bit-identical output, VMEM-resident on TPU, interpret mode
-    elsewhere; the staging appends and counters stay in XLA.
+    on CPU; the staging appends and counters stay in XLA.
     """
     R = rows.shape[0]
     F = q.front_cap
@@ -2096,7 +2096,7 @@ def tiered3_queue_extract(q: Tiered3DeviceQueue, max_len: int, lookaheads,
     rule + prefix pop) as one Pallas kernel
     (:func:`repro.kernels.queue_front.window_extract`) — bit-identical
     output, front columns stay in VMEM on TPU, interpret mode
-    elsewhere.  The bounded refill itself stays in XLA (it is the rare
+    on CPU.  The bounded refill itself stays in XLA (it is the rare
     amortized path, not the per-batch one).
 
     ``bound`` optionally caps the candidate set at a lexicographic

@@ -121,7 +121,6 @@ from typing import Callable, NamedTuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core import validate as _validate
@@ -852,13 +851,13 @@ class ShardedDeviceEngine(DeviceEngine):
 
         qspec = StackedShardedQueue(q=P("shards"), size=P(),
                                     next_seq=P(), dropped=P())
-        run = shard_map(
+        run = jax.shard_map(
             mapped, mesh=self._mesh,
             in_specs=(P(), qspec, P(), P(), P()),
             out_specs=(P(), qspec, P()),
             # state/stats/counters are replicated by construction
             # (identical deterministic compute per device); rep
             # checking cannot see through the redundant dispatch.
-            check_rep=False,
+            check_vma=False,
         )
         return run(state, stq, t_end, max_batches, stats0)

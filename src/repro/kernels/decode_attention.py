@@ -20,7 +20,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._pallas_compat import CompilerParams
 
 _NEG_INF = -1e30
 
@@ -113,7 +112,7 @@ def decode_attention_pallas(q, k_cache, v_cache, lengths, *,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, KV, G, D), q.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

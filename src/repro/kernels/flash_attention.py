@@ -21,7 +21,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._pallas_compat import CompilerParams
 
 _NEG_INF = -1e30
 
@@ -124,7 +123,7 @@ def flash_attention_pallas(q, k, v, *, causal: bool = True,
             pltpu.VMEM((block_q,), jnp.float32),      # running denom l
             pltpu.VMEM((block_q, D), jnp.float32),    # output accumulator
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary"),
         ),

@@ -32,8 +32,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._pallas_compat import CompilerParams
-
 
 def _mamba_kernel(xdt_ref, dt_ref, b_ref, c_ref, a_ref, o_ref, h_scr, *,
                   chunk: int):
@@ -100,7 +98,7 @@ def mamba_scan_pallas(xdt, dt, bc, cc, a, *, chunk: int = 32,
                                lambda b, ib, c: (b, c, ib)),
         out_shape=jax.ShapeDtypeStruct((B, Tp, I), jnp.float32),
         scratch_shapes=[pltpu.VMEM((block_i, N), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -1,37 +1,32 @@
 """Pallas kernels for the tiered3 queue's front-tier hot loops.
 
 The XLA shapes of these two loops (all-pairs rank + gather +
-``dynamic_slice``) were deliberately tuned for XLA:CPU, where sort
-custom calls and scatters carry large fixed overhead (DESIGN.md §4.4).
-On TPU that is the wrong trade: each pass re-materializes the
-front-tier columns through HBM.  These kernels run the same math as
-ONE Pallas program per call with every operand resident in VMEM, so
-the per-batch extract→dispatch→insert round trip never leaves the
-core's local memory:
+``dynamic_slice``) were tuned for XLA:CPU, where sort custom calls and
+scatters carry large fixed overhead (DESIGN.md §4.4).  These kernels
+run the same math as ONE Pallas program per call with every operand
+resident in VMEM:
 
 * :func:`window_extract` — the §III-B dynamic-lookahead take rule over
-  the (already refilled) sorted front plus the prefix pop, fused into
-  one kernel: window bounds, exclusive cummin, prefix-AND, and the
-  shift-left of all four front columns.
+  the (already refilled) sorted front plus the prefix pop.
 * :func:`front_merge` — the front counting-merge of the per-batch emit
   insert (:func:`repro.core.queue._tiered_fill_finish`): lex-rank the
-  emit rows, locate each insertion point against the sorted front
-  (searchsorted as an all-pairs count), and rebuild the merged
-  ``front_cap + R`` columns by position arithmetic — no sorts, no
-  scatters, gather-free (one-hot selects).
+  emit rows, locate each insertion point against the sorted front, and
+  rebuild the merged ``front_cap + R`` columns — no sorts, no gathers.
 
-Both kernels are BIT-IDENTICAL to the XLA paths (the differential
-suites in ``tests/test_queue_kernels.py`` pin this against the tiered3
-XLA path and the reference queue spec).  Selected via
+Both are BIT-IDENTICAL to the XLA paths (the differential suites in
+``tests/test_queue_kernels.py`` pin this).  Selected via
 ``DeviceEngine(queue_kernels="pallas")`` /
-``tiered3_queue_extract(..., kernels="pallas")``.  Off-TPU the kernels
-execute in interpret mode (the repo-wide idiom, see
-:mod:`repro.kernels.ops`); TPU compilation goes through Mosaic with
-:mod:`repro.kernels._pallas_compat` resolving the compiler-params API
-drift.
+``tiered3_queue_extract(..., kernels="pallas")``.
 
-Scalar operands (``length``, ``front_n``) travel as 1-element arrays;
-iotas are built 2-D (``broadcasted_iota``) per the TPU lowering rules.
+Layout.  The wrappers pack the queue's columns into ONE int32 tile
+array, one column per sublane row (time and args as their f32 bit
+patterns), with the slot index on lanes padded to a multiple of 128 by
+free-slot sentinels.  Every selection inside a kernel is then a masked
+select or a lane roll of whole rows, which keeps the bits exact
+(including ``-0.0`` and ``inf``) and keeps Mosaic on aligned 2-D
+vectors.  Scalars (lookaheads, the horizon cap, ``front_n``) travel in
+SMEM.  Off-TPU the kernels run in interpret mode
+(:func:`repro.kernels.interpret_mode`).
 """
 
 from __future__ import annotations
@@ -41,63 +36,113 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._pallas_compat import CompilerParams
+from repro.kernels import interpret_mode
 
 _I32_MAX = 2**31 - 1
+_INF_BITS = 0x7F800000          # f32 +inf as int32 bits
+_FAR = 2**30                    # insertion position of rows not merged
+_LANES = 128
+_SUBLANES = 8
+_TIME, _TYPE, _SEQ, _ARG0 = 0, 1, 2, 3   # packed row of each column
 
 
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+def _round_up(n: int, m: int) -> int:
+    return -(-n // m) * m
 
 
-def _iota(n: int, m: int):
-    """2-D i32 iota along dim 0 — the TPU-safe construction."""
-    return jax.lax.broadcasted_iota(jnp.int32, (n, m), 0)
+def _bits(x):
+    return jax.lax.bitcast_convert_type(x, jnp.int32)
+
+
+def _f32(x):
+    return jax.lax.bitcast_convert_type(x, jnp.float32)
+
+
+def _iota(shape, dim):
+    return jax.lax.broadcasted_iota(jnp.int32, shape, dim)
+
+
+def _fill_rows(shape):
+    """Free-slot sentinel per packed row: time inf, type -1, seq max,
+    args 0 — broadcast over ``shape`` (rows on axis 0)."""
+    r = _iota(shape, 0)
+    return jnp.where(r == _TIME, _INF_BITS,
+                     jnp.where(r == _TYPE, -1,
+                               jnp.where(r == _SEQ, _I32_MAX, 0)))
+
+
+def _pack(times, types, seqs, args, width: int):
+    """Columns -> ``i32[round_up(3 + W, 8), width]``, slot on lanes,
+    lanes past the column length holding the free-slot sentinel."""
+    n, W = args.shape
+    rows = [_bits(jnp.asarray(times, jnp.float32)),
+            jnp.asarray(types, jnp.int32),
+            jnp.asarray(seqs, jnp.int32)]
+    rows += [_bits(args[:, w].astype(jnp.float32)) for w in range(W)]
+    C = _round_up(len(rows), _SUBLANES)
+    packed = jnp.stack(rows)
+    if C > len(rows):
+        packed = jnp.concatenate(
+            [packed, jnp.zeros((C - len(rows), n), jnp.int32)])
+    if width > n:
+        packed = jnp.concatenate([packed, _fill_rows((C, width - n))],
+                                 axis=1)
+    return packed
+
+
+def _unpack(packed, n: int, W: int):
+    """Inverse of :func:`_pack` over the first ``n`` lanes."""
+    p = packed[:, :n]
+    args = _f32(p[_ARG0:_ARG0 + W]).T
+    return _f32(p[_TIME]), p[_TYPE], args, p[_SEQ]
 
 
 # ---------------------------------------------------------------------------
 # Window extract (take rule + prefix pop)
 # ---------------------------------------------------------------------------
 
-def _window_extract_kernel(
-    t_ref, y_ref, a_ref, s_ref, la_ref, cap_ref,
-    ts_ref, tys_ref, args_ref, len_ref,
-    nt_ref, ny_ref, na_ref, ns_ref,
-    *, k: int, F: int,
-):
-    # Front columns arrive padded to F + k with free-slot sentinels so
-    # the pop shift below stays in bounds for any length <= k.
-    ts_k = t_ref[0:k]
-    tys_k = y_ref[0:k]
+def _window_extract_kernel(la_ref, cap_ref, p_ref, win_ref, len_ref, out_ref,
+                           *, k: int, F: int):
+    C, FP = p_ref.shape
+    KL = win_ref.shape[1]
+    KP = _round_up(k, _SUBLANES)
     T = la_ref.shape[0]
-    valid = tys_k >= 0
-    tyc = jnp.clip(tys_k, 0, T - 1)
-    # Lookahead lookup as a one-hot select (gather-free on TPU).
-    la_all = la_ref[...]
-    sel = tyc[:, None] == _iota(T, k).T
-    la = jnp.sum(jnp.where(sel, la_all[None, :], 0.0), axis=1)
-    wins = jnp.where(valid, ts_k + la, jnp.inf)
 
-    # Exclusive cummin of the window bounds + prefix-AND stop rule,
-    # both as k×k all-pairs forms (k is max_batch_len — tiny).
-    i2 = _iota(k, k)          # [i, j] = i
-    j2 = i2.T                 # [i, j] = j
-    t_max = jnp.min(jnp.where(j2 < i2, wins[None, :], jnp.inf), axis=1)
-    ok = valid & (ts_k <= jnp.minimum(t_max, cap_ref[0]))
-    take = jnp.sum((j2 <= i2) & ~ok[None, :], axis=1) == 0
-    length = jnp.sum(take).astype(jnp.int32)
+    head = p_ref[:, :KL]
+    t = _f32(p_ref[_TIME:_TIME + 1, :KL])                  # [1, KL]
+    y = p_ref[_TYPE:_TYPE + 1, :KL]
+    valid = y >= 0
+    yc = jnp.clip(y, 0, T - 1)
+    la = jnp.zeros(t.shape, jnp.float32)
+    for ty in range(T):                                    # SMEM lookup
+        la = jnp.where(yc == ty, la_ref[ty], la)
+    wins = jnp.where(valid, t + la, jnp.inf)
 
-    ts_ref[...] = jnp.where(take, ts_k, 0.0)
-    tys_ref[...] = jnp.where(take, tys_k, 0)
-    args_ref[...] = jnp.where(take[:, None], a_ref[0:k, :], 0.0)
-    len_ref[0] = length
+    # Candidate i on sublanes, slot j on lanes.  Exclusive cummin of
+    # the window bounds; the candidate's own time/type by diagonal
+    # select; the prefix-AND stop rule is "length = first rejection".
+    i2 = _iota((KP, KL), 0)
+    j2 = _iota((KP, KL), 1)
+    t_max = jnp.min(jnp.where(j2 < i2, wins, jnp.inf), axis=1, keepdims=True)
+    t_i = jnp.min(jnp.where(j2 == i2, t, jnp.inf), axis=1, keepdims=True)
+    v_i = jnp.max(jnp.where(j2 == i2, y, -1), axis=1, keepdims=True) >= 0
+    i_col = _iota((KP, 1), 0)
+    ok = v_i & (t_i <= jnp.minimum(t_max, cap_ref[0])) & (i_col < k)
+    length = jnp.min(jnp.where(ok, k, i_col), axis=0, keepdims=True)
 
-    # Prefix pop: shift every (padded) front column left by `length`.
-    nt_ref[...] = pl.load(t_ref, (pl.ds(length, F),))
-    ny_ref[...] = pl.load(y_ref, (pl.ds(length, F),))
-    na_ref[...] = pl.load(a_ref, (pl.ds(length, F), slice(None)))
-    ns_ref[...] = pl.load(s_ref, (pl.ds(length, F),))
+    win_ref[...] = jnp.where(_iota((C, KL), 1) < length, head, 0)
+    len_ref[...] = jnp.broadcast_to(length, len_ref.shape)
+
+    # Prefix pop: shift every row left by `length`, one static lane
+    # roll per bit of it, then sentinel-fill the vacated tail.
+    x = p_ref[...]
+    for b in range(k.bit_length()):
+        bit = ((length >> b) & 1) == 1
+        x = jnp.where(bit, pltpu.roll(x, FP - (1 << b), 1), x)
+    lane = _iota((C, FP), 1)
+    out_ref[...] = jnp.where(lane + length < F, x, _fill_rows((C, FP)))
 
 
 @partial(jax.jit, static_argnames=("k", "interpret"))
@@ -114,119 +159,104 @@ def window_extract(f_times, f_types, f_args, f_seqs, lookaheads,
     W = f_args.shape[1]
     if k > F:
         raise ValueError(f"window width {k} exceeds front capacity {F}")
-    interpret = _interpret() if interpret is None else interpret
-    pad_t = jnp.concatenate(
-        [f_times, jnp.full((k,), jnp.inf, jnp.float32)])
-    pad_y = jnp.concatenate(
-        [f_types, jnp.full((k,), -1, jnp.int32)])
-    pad_a = jnp.concatenate(
-        [f_args, jnp.zeros((k, W), jnp.float32)])
-    pad_s = jnp.concatenate(
-        [f_seqs, jnp.full((k,), _I32_MAX, jnp.int32)])
+    interpret = interpret_mode() if interpret is None else interpret
+    FP = _round_up(F, _LANES)
+    KL = _round_up(k, _LANES)
+    packed = _pack(f_times, f_types, f_seqs, f_args, FP)
+    C = packed.shape[0]
     cap = (jnp.full((1,), jnp.inf, jnp.float32) if t_cap is None
            else jnp.asarray(t_cap, jnp.float32).reshape(1))
     la = jnp.asarray(lookaheads, jnp.float32)
 
-    out = pl.pallas_call(
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
+    win, length, out = pl.pallas_call(
         partial(_window_extract_kernel, k=k, F=F),
         out_shape=[
-            jax.ShapeDtypeStruct((k,), jnp.float32),      # ts
-            jax.ShapeDtypeStruct((k,), jnp.int32),        # tys
-            jax.ShapeDtypeStruct((k, W), jnp.float32),    # args
-            jax.ShapeDtypeStruct((1,), jnp.int32),        # length
-            jax.ShapeDtypeStruct((F,), jnp.float32),      # f_times'
-            jax.ShapeDtypeStruct((F,), jnp.int32),        # f_types'
-            jax.ShapeDtypeStruct((F, W), jnp.float32),    # f_args'
-            jax.ShapeDtypeStruct((F,), jnp.int32),        # f_seqs'
+            jax.ShapeDtypeStruct((C, KL), jnp.int32),
+            jax.ShapeDtypeStruct((_SUBLANES, _LANES), jnp.int32),
+            jax.ShapeDtypeStruct((C, FP), jnp.int32),
         ],
-        compiler_params=CompilerParams(),
+        in_specs=[smem, smem, vmem],
+        out_specs=[vmem, vmem, vmem],
         interpret=interpret,
-    )(pad_t, pad_y, pad_a, pad_s, la, cap)
-    ts, tys, args, length, nt, ny, na, ns = out
-    return ts, tys, args, length[0], nt, ny, na, ns
+    )(la, cap, packed)
+    ts, tys, args, _ = _unpack(win, k, W)
+    nt, ny, na, ns = _unpack(out, F, W)
+    return ts, tys, args, length[0, 0], nt, ny, na, ns
 
 
 # ---------------------------------------------------------------------------
 # Front counting-merge (the per-batch emit insert hot loop)
 # ---------------------------------------------------------------------------
 
-def _front_merge_kernel(
-    ft_ref, fy_ref, fa_ref, fs_ref, fn_ref,
-    rt_ref, ry_ref, ra_ref, rs_ref, ins_ref,
-    mt_ref, my_ref, ma_ref, ms_ref,
-    *, F: int, R: int,
-):
-    FE = F + R
-    front_n = fn_ref[0]
-    to_front = ins_ref[...] != 0
-    t_r = rt_ref[...]
-    seq_r = rs_ref[...]
+def _front_merge_kernel(fn_ref, p_ref, e_ref, m_ref, out_ref,
+                        *, F: int, R: int):
+    C, FEP = p_ref.shape
+    RL = e_ref.shape[1]
+    RP = _round_up(R, _SUBLANES)
 
-    # Lex-rank the emit rows by (time, seq, index) — non-front rows get
-    # (inf, I32_MAX) keys so they rank last — then select row r of the
-    # sorted order with a one-hot (the gather-free _small_lex_perm).
-    tt = jnp.where(to_front, t_r, jnp.inf)
-    ss = jnp.where(to_front, seq_r, _I32_MAX)
-    ri = _iota(R, R)          # [i, j] = i
-    rj = ri.T
-    t_gt = tt[:, None] > tt[None, :]
-    t_eq = tt[:, None] == tt[None, :]
-    s_gt = ss[:, None] > ss[None, :]
-    s_eq = ss[:, None] == ss[None, :]
-    before = t_gt | (t_eq & s_gt) | (t_eq & s_eq & (ri > rj))
-    rank = jnp.sum(before, axis=1).astype(jnp.int32)  # unique in [0, R)
-    onehot = rank[None, :] == _iota(R, R)             # [r, i]: rank[i]==r
-    rt = jnp.sum(jnp.where(onehot, tt[None, :], 0.0), axis=1)
-    ty_r = ry_ref[...]
-    arg_r = ra_ref[...]
-    rty = jnp.sum(jnp.where(onehot, ty_r[None, :], 0), axis=1)
-    rseq = jnp.sum(jnp.where(onehot, seq_r[None, :], 0), axis=1)
-    rarg = jnp.sum(
-        jnp.where(onehot[:, :, None], arg_r[None, :, :], 0.0),
-        axis=1,
+    # Emit rows: row e on lanes (row form) and, by diagonal select, on
+    # sublanes (column form) — exact for int32 bit patterns.
+    ie = _iota((RP, RL), 0)
+    je = _iota((RP, RL), 1)
+    diag = ie == je
+
+    def col(row):
+        return jnp.max(jnp.where(diag, row, jnp.iinfo(jnp.int32).min),
+                       axis=1, keepdims=True)
+
+    mask = (m_ref[...] != 0) & (_iota((1, RL), 1) < R)
+    # Rows not bound for the front sort last: (inf, I32_MAX) keys.
+    tt = jnp.where(mask, e_ref[_TIME:_TIME + 1, :], _INF_BITS)
+    ss = jnp.where(mask, e_ref[_SEQ:_SEQ + 1, :], _I32_MAX)
+    tt_row = _f32(tt)
+    tt_col = _f32(col(tt))
+    ss_col = col(ss)
+    ins_col = col(mask.astype(jnp.int32)) != 0
+
+    # Lex rank of each row by (time, seq, index) — unique in [0, R).
+    before = (tt_row < tt_col) | (
+        (tt_row == tt_col)
+        & ((ss < ss_col) | ((ss == ss_col) & (je < ie)))
     )
-    rins = jnp.any(onehot & to_front[None, :], axis=1)
+    rank = jnp.sum(jnp.where(before & (je < R), 1, 0), axis=1,
+                   keepdims=True)
 
-    # searchsorted(f_times, rt, 'right') as an all-pairs count, capped
-    # at the live occupancy (rows land after every equal-time slot —
-    # emit seqs exceed queued seqs).
+    # searchsorted(f_times, t, 'right') as an all-pairs count, capped at
+    # the live occupancy (emit seqs exceed queued seqs, so rows land
+    # after every equal-time slot).
+    ft = _f32(p_ref[_TIME:_TIME + 1, :])
+    lf = _iota((RP, FEP), 1)
     older = jnp.minimum(
-        jnp.sum(ft_ref[...][None, :] <= rt[:, None], axis=1)
-        .astype(jnp.int32),
-        front_n,
+        jnp.sum(jnp.where((ft <= tt_col) & (lf < F), 1, 0), axis=1,
+                keepdims=True),
+        fn_ref[0],
     )
-    r_idx = _iota(R, 1)[:, 0]
-    pos = jnp.where(rins, older + r_idx, FE + R)
+    pos = jnp.where(ins_col, older + rank, _FAR)            # [RP, 1]
 
-    # Position-arithmetic rebuild of the merged columns.
-    i2 = _iota(FE, R)         # [i, j] = i
-    ins_before = jnp.sum(pos[None, :] < i2, axis=1).astype(jnp.int32)
-    is_ins = (
-        jnp.sum(pos[None, :] <= i2, axis=1).astype(jnp.int32) > ins_before
-    )
-    i_idx = _iota(FE, 1)[:, 0]
-    src = jnp.where(
-        is_ins, FE + jnp.clip(ins_before, 0, R - 1),
-        jnp.clip(i_idx - ins_before, 0, FE - 1),
-    )
+    # Output slot i on lanes: inserted rows before it, and whether a row
+    # lands exactly there.
+    ins_before = jnp.sum(jnp.where(pos < lf, 1, 0), axis=0, keepdims=True)
+    hit = pos == lf                                         # [RP, FEP]
+    is_ins = jnp.max(jnp.where(hit, 1, 0), axis=0, keepdims=True) > 0
 
-    ext_t = jnp.concatenate(
-        [ft_ref[...], jnp.full((R,), jnp.inf, jnp.float32), rt])
-    ext_y = jnp.concatenate(
-        [fy_ref[...], jnp.full((R,), -1, jnp.int32), rty])
-    ext_a = jnp.concatenate(
-        [fa_ref[...], jnp.zeros((R, fa_ref.shape[1]), jnp.float32), rarg])
-    ext_s = jnp.concatenate(
-        [fs_ref[...], jnp.full((R,), _I32_MAX, jnp.int32), rseq])
+    # Front slots move right by the inserted-row count before them: one
+    # static lane roll per possible count (the padded tail is sentinel,
+    # which is what the evicted overflow region reads).
+    p = p_ref[...]
+    front = p
+    for s in range(1, R + 1):
+        front = jnp.where(ins_before == s, pltpu.roll(p, s, 1), front)
 
-    EXT = F + 2 * R
-    sel = src[:, None] == _iota(EXT, FE).T     # [i, e]: src[i] == e
-    mt_ref[...] = jnp.sum(jnp.where(sel, ext_t[None, :], 0.0), axis=1)
-    my_ref[...] = jnp.sum(jnp.where(sel, ext_y[None, :], 0), axis=1)
-    ms_ref[...] = jnp.sum(jnp.where(sel, ext_s[None, :], 0), axis=1)
-    ma_ref[...] = jnp.sum(
-        jnp.where(sel[:, :, None], ext_a[None, :, :], 0.0), axis=1
-    )
+    # Inserted slots take their row's packed column values.
+    r_idx = _iota((C, FEP), 0)
+    ins = jnp.zeros((C, FEP), jnp.int32)
+    for c in range(C):
+        val = jnp.sum(jnp.where(hit, col(e_ref[c:c + 1, :]), 0), axis=0,
+                      keepdims=True)
+        ins = jnp.where(r_idx == c, val, ins)
+    out_ref[...] = jnp.where(is_ins, ins, front)
 
 
 @partial(jax.jit, static_argnames=("interpret",))
@@ -245,27 +275,24 @@ def front_merge(f_times, f_types, f_args, f_seqs, front_n,
     F = f_times.shape[0]
     R = t_r.shape[0]
     W = f_args.shape[1]
-    interpret = _interpret() if interpret is None else interpret
+    interpret = interpret_mode() if interpret is None else interpret
+    FE = F + R
+    FEP = _round_up(FE, _LANES)
+    RL = _round_up(R, _LANES)
+    front = _pack(f_times, f_types, f_seqs, f_args, FEP)
+    emits = _pack(t_r, ty_r, seq_r, jnp.asarray(arg_r, jnp.float32), RL)
+    mask = jnp.zeros((1, RL), jnp.int32).at[0, :R].set(
+        jnp.asarray(to_front, jnp.int32))
+    fn = jnp.asarray(front_n, jnp.int32).reshape(1)
+
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
     out = pl.pallas_call(
         partial(_front_merge_kernel, F=F, R=R),
-        out_shape=[
-            jax.ShapeDtypeStruct((F + R,), jnp.float32),
-            jax.ShapeDtypeStruct((F + R,), jnp.int32),
-            jax.ShapeDtypeStruct((F + R, W), jnp.float32),
-            jax.ShapeDtypeStruct((F + R,), jnp.int32),
-        ],
-        compiler_params=CompilerParams(),
+        out_shape=jax.ShapeDtypeStruct(front.shape, jnp.int32),
+        in_specs=[smem, vmem, vmem, vmem],
+        out_specs=vmem,
         interpret=interpret,
-    )(
-        jnp.asarray(f_times, jnp.float32),
-        jnp.asarray(f_types, jnp.int32),
-        jnp.asarray(f_args, jnp.float32),
-        jnp.asarray(f_seqs, jnp.int32),
-        jnp.asarray(front_n, jnp.int32).reshape(1),
-        jnp.asarray(t_r, jnp.float32),
-        jnp.asarray(ty_r, jnp.int32),
-        jnp.asarray(arg_r, jnp.float32),
-        jnp.asarray(seq_r, jnp.int32),
-        jnp.asarray(to_front, jnp.int32),
-    )
-    return tuple(out)
+    )(fn, front, emits, mask)
+    mt, my, ma, ms = _unpack(out, FE, W)
+    return mt, my, ma, ms

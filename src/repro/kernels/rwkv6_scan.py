@@ -18,7 +18,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._pallas_compat import CompilerParams
 
 _CLIP = 30.0
 
@@ -103,7 +102,7 @@ def rwkv6_scan_pallas(r, k, v, logw, u, *, chunk: int = 64,
                                lambda b, h, c: (b, h, c, 0)),
         out_shape=jax.ShapeDtypeStruct((B, H, Tp, K), jnp.float32),
         scratch_shapes=[pltpu.VMEM((K, K), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

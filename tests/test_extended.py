@@ -125,7 +125,6 @@ def test_device_queue_fifo_ties():
 # ---------------------------------------------------------------------------
 
 def test_compressed_psum_under_shard_map():
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.training.compression import compressed_psum_gradients
@@ -136,7 +135,7 @@ def test_compressed_psum_under_shard_map():
     def f(g):
         return compressed_psum_gradients(g, mesh, ("data",))
 
-    out = shard_map(f, mesh=mesh, in_specs=(P(),), out_specs=P())(grads)
+    out = jax.shard_map(f, mesh=mesh, in_specs=(P(),), out_specs=P())(grads)
     err = jnp.abs(out["w"] - grads["w"])
     assert float(err.max()) < 1e-2  # int8 quantization error bound
 

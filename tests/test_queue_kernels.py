@@ -28,6 +28,14 @@ from repro.kernels.queue_front import front_merge, window_extract
 from repro import poc
 from repro.core.program import Config
 
+# The queue ops jitted once per shape: called eagerly, every lax.cond
+# inside them is traced and compiled again on every step.
+_fill = jax.jit(tiered3_queue_fill_rows, static_argnames=("kernels",))
+_fill_tagged = jax.jit(tiered3_queue_fill_rows_tagged,
+                       static_argnames=("kernels",))
+_extract = jax.jit(tiered3_queue_extract, static_argnums=(1,),
+                   static_argnames=("kernels",))
+
 
 def _assert_queues_equal(qa, qb, msg=""):
     for f in qa._fields:
@@ -56,15 +64,13 @@ def _run_differential(front_cap, stage_cap, capacity, *, steps, R, k,
     )
     for step in range(steps):
         rows = _rand_rows(rng, R, la.shape[0], W)
-        qx = tiered3_queue_fill_rows(qx, rows)
-        qp = tiered3_queue_fill_rows(qp, rows, kernels="pallas")
+        qx = _fill(qx, rows)
+        qp = _fill(qp, rows, kernels="pallas")
         _assert_queues_equal(qx, qp, f"fill step {step}")
         if step % 3 == 2:
             cap = None if step % 2 else t_cap
-            qx, ts1, ty1, a1, l1 = tiered3_queue_extract(qx, k, la, cap)
-            qp, ts2, ty2, a2, l2 = tiered3_queue_extract(
-                qp, k, la, cap, kernels="pallas"
-            )
+            qx, ts1, ty1, a1, l1 = _extract(qx, k, la, cap)
+            qp, ts2, ty2, a2, l2 = _extract(qp, k, la, cap, kernels="pallas")
             np.testing.assert_array_equal(np.asarray(ts1), np.asarray(ts2))
             np.testing.assert_array_equal(np.asarray(ty1), np.asarray(ty2))
             np.testing.assert_array_equal(np.asarray(a1), np.asarray(a2))
@@ -96,10 +102,8 @@ def test_tagged_fill_differential():
         )
         next_seq += 5
         insert = jnp.asarray(rng.random(5) < 0.8)
-        qx = tiered3_queue_fill_rows_tagged(qx, rows, seqs, insert)
-        qp = tiered3_queue_fill_rows_tagged(
-            qp, rows, seqs, insert, kernels="pallas"
-        )
+        qx = _fill_tagged(qx, rows, seqs, insert)
+        qp = _fill_tagged(qp, rows, seqs, insert, kernels="pallas")
         _assert_queues_equal(qx, qp, f"tagged step {step}")
 
 
@@ -281,3 +285,18 @@ def test_engine_pallas_parity_poc_long():
     assert int(pal.state) == int(base.state)
     assert pal.batches == base.batches
     assert int(base.state) == poc.reference_final_sum(types, 16)
+
+
+@pytest.mark.parametrize("backend,expected", [
+    ("cpu", True), ("tpu", False), ("gpu", RuntimeError)])
+def test_interpret_mode_by_backend(monkeypatch, backend, expected):
+    """Interpret on CPU, Mosaic on TPU, and never a quiet interpret on
+    any other backend."""
+    from repro.kernels import interpret_mode
+
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    if expected is RuntimeError:
+        with pytest.raises(RuntimeError, match=backend):
+            interpret_mode()
+    else:
+        assert interpret_mode() is expected
