@@ -55,7 +55,6 @@ on how events were grouped into batches.
 
 from __future__ import annotations
 
-import time as _time
 from typing import Any, Callable, Sequence
 
 import jax
@@ -108,8 +107,6 @@ class _ComposerBase:
         self.codec = codec
         self._programs: dict[int, Callable] = {}   # code -> jitted fn
         self._words: dict[int, tuple[int, ...]] = {}
-        self.compile_seconds: dict[int, float] = {}
-        self.trace_count = 0
         # Per-word execution histogram (code -> dispatch count): the
         # host-side profiling source for hot-word selection
         # (:func:`hot_words_from_counts`); the device engine keeps the
@@ -127,15 +124,11 @@ class _ComposerBase:
         # Timestamps are traced values (donated by the scheduler); the
         # batch structure itself is baked into the program — exactly the
         # paper's "batch = compiled contiguous procedure".
-        jfn = jax.jit(fn)
-        self.trace_count += 1
-        return jfn
+        return jax.jit(fn)
 
     def program(self, code: int) -> Callable:
         if code not in self._programs:
-            t0 = _time.perf_counter()
             self._programs[code] = self._build(code)
-            self.compile_seconds[code] = _time.perf_counter() - t0
         return self._programs[code]
 
     def execute(self, code: int, state, ts, args):
@@ -171,8 +164,6 @@ class EagerComposer(_ComposerBase):
         self.aot = aot and state_spec is not None
         self.state_spec = state_spec
         self.arg_spec = arg_spec
-        self.total_compile_seconds = 0.0
-        t0 = _time.perf_counter()
         for code in codec.enumerate_codes():
             word = self.word_for(code)
             if not word:
@@ -181,18 +172,13 @@ class EagerComposer(_ComposerBase):
                 self._programs[code] = self._aot_build(code, word)
             else:
                 self._programs[code] = self._build(code)
-        self.total_compile_seconds = _time.perf_counter() - t0
 
     def _aot_build(self, code, word):
         fn = compose_word_fn(self.registry, word)
         k = len(word)
         ts_spec = [jax.ShapeDtypeStruct((), jnp.float32)] * k
         args_spec = [self.arg_spec] * k
-        t0 = _time.perf_counter()
-        compiled = jax.jit(fn).lower(self.state_spec, ts_spec, args_spec).compile()
-        self.compile_seconds[code] = _time.perf_counter() - t0
-        self.trace_count += 1
-        return compiled
+        return jax.jit(fn).lower(self.state_spec, ts_spec, args_spec).compile()
 
     def execute(self, code, state, ts, args):
         self.execute_counts[code] = self.execute_counts.get(code, 0) + 1

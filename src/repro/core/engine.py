@@ -109,6 +109,7 @@ from repro.core.queue import (
     tiered_queue_occupancy,
 )
 from repro.core import validate as _validate
+from repro.core.spans import DISPATCH, EXTRACT, INSERT
 from repro.core.validate import FAULT_CLOCK, FAULT_OVERFLOW, EngineFaultError
 from repro.core.scheduler import (
     ConservativeScheduler,
@@ -522,6 +523,7 @@ class DeviceEngine:
         return q, rows, np.asarray(spill, np.int32)
 
     # -- extraction (paper Fig 2) --------------------------------------------
+    @jax.named_scope(EXTRACT)
     def _extract(self, queue, t_cap=None, bound=None):
         if self.queue_mode == "tiered":
             return tiered_queue_extract(
@@ -541,6 +543,7 @@ class DeviceEngine:
         )
 
     # -- dispatch -------------------------------------------------------------
+    @jax.named_scope(DISPATCH)
     def _dispatch_window(self, state, ts, tys, args, length):
         """Dispatch one extracted window; returns (state, emits).
 
@@ -802,10 +805,12 @@ class DeviceEngine:
                 queue, ts, tys, args, length = self._extract(queue, t_end)
             prev_time = stats["time"]
             state, emits = self._dispatch_window(state, ts, tys, args, length)
-            if spill:
-                queue, spill_delta = self._spill_insert(queue, emits, stats)
-            else:
-                queue = insert(queue, emits)
+            with jax.named_scope(INSERT):
+                if spill:
+                    queue, spill_delta = self._spill_insert(
+                        queue, emits, stats)
+                else:
+                    queue = insert(queue, emits)
             last_t = ts[jnp.maximum(length - 1, 0)]
             new_stats = {
                 "batches": stats["batches"] + 1,

@@ -55,6 +55,8 @@ touched entities.
 
 from __future__ import annotations
 
+import contextlib
+import contextlib
 import dataclasses
 import functools
 import warnings
@@ -64,6 +66,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core import spans
+from repro.core import spans
 from repro.core.events import ARG_WIDTH, EventRegistry
 from repro.core.queue import HostEventQueue
 
@@ -1040,156 +1044,178 @@ class CompiledSim:
 
         eng = self.engine
         streamed = feeder is not None
-        while True:
-            progressed = False
-            if spill and pool_seqs.size:
-                queue, pool_rows, pool_seqs, stats = \
-                    self._absorb_spill(queue, pool_rows, pool_seqs, stats)
-            # -- streamed admission: at most ONE arrival block per
-            # boundary, so the admitted/spilled/shed split is a pure
-            # function of the cursor, the horizon, and queue occupancy
-            # — never of prefetch timing.
-            if streamed and feeder.has_pending():
-                # Arrivals past the horizon are never consumed: they
-                # stay in the source, like queued events past t_end
-                # stay in the queue.
-                adm = feeder.admissible(t_end)
-                if adm:
-                    occ = int(np.asarray(eng.queue_occupancy(queue)))
-                    k = min(adm, max(eng.capacity - occ, 0))
-                    if k > 0:
-                        rows_d, seqs_d, lo = feeder.device_block()
-                        queue = self._absorb_fn()(
-                            queue, rows_d, seqs_d,
-                            jnp.int32(lo), jnp.int32(lo + k),
-                        )
-                        feeder.advance(k)
-                        ingested += k
-                        progressed = True
-                    rest = adm - k
-                    if rest > 0:
-                        if spill:
-                            r_rows, r_seqs = feeder.host_slice(rest)
-                            pool_rows = np.concatenate(
-                                [pool_rows, r_rows])
-                            pool_seqs = np.concatenate(
-                                [pool_seqs, r_seqs])
-                            feeder.advance(rest)
-                            ingested += rest
-                            progressed = True
-                        elif backpressure == "shed":
-                            feeder.advance(rest)
-                            ingested += rest
-                            shed += rest
-                            progressed = True
-                        elif backpressure == "error":
-                            raise EngineFaultError(
-                                FAULT_INGEST,
-                                0 if stats is None
-                                else int(np.asarray(stats["batches"])),
-                                detail=(
-                                    f"{rest} arrival(s) found the "
-                                    f"capacity-{eng.capacity} queue "
-                                    "full (backpressure='error')"
-                                ),
-                            )
-                        # backpressure='block': the rows wait in the
-                        # feeder; the fence keeps order safe and the
-                        # stall detector below converts a wedged
-                        # topology into FAULT_INGEST.
-            if streamed:
-                # Refresh the admission fence: the lex-min outstanding
-                # external key — next unconsumed arrival vs. spilled
-                # pool head — with (inf, I32_MAX) meaning no fence.
-                stats = dict(eng.initial_run_stats()
-                             if stats is None else stats)
-                f_t, f_s = feeder.next_key()
+        span = jax.profiler.TraceAnnotation
+        # One ``des.boundary`` span per boundary: it opens after each
+        # segment (and before the first) and closes as the next starts.
+        with contextlib.ExitStack() as boundary:
+            boundary.enter_context(span(spans.BOUNDARY))
+            while True:
+                progressed = False
                 if spill and pool_seqs.size:
-                    order = np.lexsort((pool_seqs, pool_rows[:, 0]))
-                    p_key = (float(pool_rows[order[0], 0]),
-                             int(pool_seqs[order[0]]))
-                    if p_key < (f_t, f_s):
-                        f_t, f_s = p_key
-                stats["bound_t"] = jnp.float32(f_t)
-                stats["bound_seq"] = jnp.int32(f_s)
-            done = 0 if stats is None else int(np.asarray(stats["batches"]))
-            target = (total_batches if seg is None
-                      else min(total_batches, done + seg))
-            state, queue, stats = eng.run(
-                state, queue, max_batches=target, t_end=t_end, stats=stats
-            )
-            new_done = int(stats["batches"])
-            if new_done > done:
-                progressed = True
-            if spill and int(np.asarray(stats.get("spill_n", 0))) > 0:
-                n = int(stats["spill_n"])
-                pool_rows = np.concatenate(
-                    [pool_rows, np.asarray(stats["spill_rows"])[:n]]
-                )
-                pool_seqs = np.concatenate(
-                    [pool_seqs, np.asarray(stats["spill_seqs"])[:n]]
-                )
-                stats = dict(stats)
-                stats["spill_n"] = jnp.int32(0)
-            seg_index += 1
-            # Save BEFORE the injection seam: the newest checkpoint is
-            # always a clean pre-corruption snapshot, so fault recovery
-            # is restore-latest-and-replay.
-            if manager is not None and seg is not None:
-                self._save_checkpoint(
-                    manager, new_done, state, queue, stats,
-                    pool_rows, pool_seqs,
-                    extra=(dict(
-                        ingest_cursor=np.int64(feeder.cursor),
-                        ingested=np.int64(ingested),
-                        shed=np.int64(shed),
-                    ) if streamed else None),
-                    strip=(("bound_t", "bound_seq")
-                           if streamed and not spill else ()),
-                )
-            if segment_hook is not None:
-                out = segment_hook(seg_index, state, queue, stats)
-                if out is not None:
-                    state, queue, stats = out
-            if new_done >= total_batches:
-                break
-            pool_live = bool(spill and pool_seqs.size)
-            feeder_live = streamed and feeder.has_pending()
-            if pool_live or feeder_live:
-                qt = self._queue_next_time(queue)
-                pool_t = (float(pool_rows[:, 0].min()) if pool_live
-                          else float("inf"))
-                feed_t = (feeder.next_time() if feeder_live
-                          else float("inf"))
-                if qt > t_end and pool_t > t_end and feed_t > t_end:
-                    # Everything outstanding is past the horizon — the
-                    # external remainder stays pending, like the
-                    # queue's own post-horizon events.
-                    break
-                if not progressed:
-                    idle_rounds += 1
-                    # One idle round is legal (the absorb/rebalance
-                    # runs NEXT iteration); repeated idleness means
-                    # the fence can never clear.
-                    if idle_rounds >= 3:
-                        word = (FAULT_INGEST if feeder_live
-                                else FAULT_SPILL_STALL)
-                        n_out = (int(pool_seqs.size) if pool_live
-                                 else feeder.n - feeder.cursor)
-                        raise EngineFaultError(
-                            word, new_done,
-                            detail=(f"{n_out} external event(s) "
-                                    "outstanding but no segment can "
-                                    "make progress"),
+                    with span(spans.SPILL):
+                        queue, pool_rows, pool_seqs, stats = \
+                            self._absorb_spill(
+                                queue, pool_rows, pool_seqs, stats)
+                # -- streamed admission: at most ONE arrival block per
+                # boundary, so the admitted/spilled/shed split is a pure
+                # function of the cursor, the horizon, and queue occupancy
+                # — never of prefetch timing.
+                if streamed and feeder.has_pending():
+                    # Arrivals past the horizon are never consumed: they
+                    # stay in the source, like queued events past t_end
+                    # stay in the queue.
+                    adm = feeder.admissible(t_end)
+                    if adm:
+                        with span(spans.OCCUPANCY):
+                            occ = int(np.asarray(eng.queue_occupancy(queue)))
+                        k = min(adm, max(eng.capacity - occ, 0))
+                        if k > 0:
+                            with span(spans.ABSORB, rows=k):
+                                rows_d, seqs_d, lo = feeder.device_block()
+                                queue = self._absorb_fn()(
+                                    queue, rows_d, seqs_d,
+                                    jnp.int32(lo), jnp.int32(lo + k),
+                                )
+                            feeder.advance(k)
+                            ingested += k
+                            progressed = True
+                        rest = adm - k
+                        if rest > 0:
+                            if spill:
+                                r_rows, r_seqs = feeder.host_slice(rest)
+                                pool_rows = np.concatenate(
+                                    [pool_rows, r_rows])
+                                pool_seqs = np.concatenate(
+                                    [pool_seqs, r_seqs])
+                                feeder.advance(rest)
+                                ingested += rest
+                                progressed = True
+                            elif backpressure == "shed":
+                                feeder.advance(rest)
+                                ingested += rest
+                                shed += rest
+                                progressed = True
+                            elif backpressure == "error":
+                                raise EngineFaultError(
+                                    FAULT_INGEST,
+                                    0 if stats is None
+                                    else int(np.asarray(stats["batches"])),
+                                    detail=(
+                                        f"{rest} arrival(s) found the "
+                                        f"capacity-{eng.capacity} queue "
+                                        "full (backpressure='error')"
+                                    ),
+                                )
+                            # backpressure='block': the rows wait in the
+                            # feeder; the fence keeps order safe and the
+                            # stall detector below converts a wedged
+                            # topology into FAULT_INGEST.
+                if streamed:
+                    # Refresh the admission fence: the lex-min outstanding
+                    # external key — next unconsumed arrival vs. spilled
+                    # pool head — with (inf, I32_MAX) meaning no fence.
+                    # Reading the next key may wait for the feeder's
+                    # next block.
+                    with span(spans.FENCE):
+                        stats = dict(eng.initial_run_stats()
+                                     if stats is None else stats)
+                        f_t, f_s = feeder.next_key()
+                        if spill and pool_seqs.size:
+                            order = np.lexsort(
+                                (pool_seqs, pool_rows[:, 0]))
+                            p_key = (float(pool_rows[order[0], 0]),
+                                     int(pool_seqs[order[0]]))
+                            if p_key < (f_t, f_s):
+                                f_t, f_s = p_key
+                        stats["bound_t"] = jnp.float32(f_t)
+                        stats["bound_seq"] = jnp.int32(f_s)
+                done = (0 if stats is None
+                        else int(np.asarray(stats["batches"])))
+                target = (total_batches if seg is None
+                          else min(total_batches, done + seg))
+                boundary.close()
+                with span(spans.SEGMENT):
+                    state, queue, stats = eng.run(
+                        state, queue, max_batches=target, t_end=t_end,
+                        stats=stats,
+                    )
+                    new_done = int(stats["batches"])
+                boundary.enter_context(span(spans.BOUNDARY))
+                if new_done > done:
+                    progressed = True
+                if spill:
+                    with span(spans.SPILL):
+                        n = int(np.asarray(stats.get("spill_n", 0)))
+                        if n > 0:
+                            pool_rows = np.concatenate([
+                                pool_rows,
+                                np.asarray(stats["spill_rows"])[:n]])
+                            pool_seqs = np.concatenate([
+                                pool_seqs,
+                                np.asarray(stats["spill_seqs"])[:n]])
+                            stats = dict(stats)
+                            stats["spill_n"] = jnp.int32(0)
+                seg_index += 1
+                # Save BEFORE the injection seam: the newest checkpoint is
+                # always a clean pre-corruption snapshot, so fault recovery
+                # is restore-latest-and-replay.
+                if manager is not None and seg is not None:
+                    with span(spans.CHECKPOINT):
+                        self._save_checkpoint(
+                            manager, new_done, state, queue, stats,
+                            pool_rows, pool_seqs,
+                            extra=(dict(
+                                ingest_cursor=np.int64(feeder.cursor),
+                                ingested=np.int64(ingested),
+                                shed=np.int64(shed),
+                            ) if streamed else None),
+                            strip=(("bound_t", "bound_seq")
+                                   if streamed and not spill else ()),
                         )
-                else:
-                    idle_rounds = 0
-                continue
-            if new_done < target:
-                # Loop exited before its batch target: drained, horizon,
-                # or admission fence with nothing outstanding — all
-                # terminal.
-                break
+                if segment_hook is not None:
+                    out = segment_hook(seg_index, state, queue, stats)
+                    if out is not None:
+                        state, queue, stats = out
+                if new_done >= total_batches:
+                    break
+                pool_live = bool(spill and pool_seqs.size)
+                feeder_live = streamed and feeder.has_pending()
+                if pool_live or feeder_live:
+                    with span(spans.NEXT_TIME):
+                        qt = self._queue_next_time(queue)
+                        pool_t = (float(pool_rows[:, 0].min()) if pool_live
+                                  else float("inf"))
+                        feed_t = (feeder.next_time() if feeder_live
+                                  else float("inf"))
+                    if qt > t_end and pool_t > t_end and feed_t > t_end:
+                        # Everything outstanding is past the horizon — the
+                        # external remainder stays pending, like the
+                        # queue's own post-horizon events.
+                        break
+                    if not progressed:
+                        idle_rounds += 1
+                        # One idle round is legal (the absorb/rebalance
+                        # runs NEXT iteration); repeated idleness means
+                        # the fence can never clear.
+                        if idle_rounds >= 3:
+                            word = (FAULT_INGEST if feeder_live
+                                    else FAULT_SPILL_STALL)
+                            n_out = (int(pool_seqs.size) if pool_live
+                                     else feeder.n - feeder.cursor)
+                            raise EngineFaultError(
+                                word, new_done,
+                                detail=(f"{n_out} external event(s) "
+                                        "outstanding but no segment can "
+                                        "make progress"),
+                            )
+                    else:
+                        idle_rounds = 0
+                    continue
+                if new_done < target:
+                    # Loop exited before its batch target: drained, horizon,
+                    # or admission fence with nothing outstanding — all
+                    # terminal.
+                    break
         return state, queue, stats, pool_rows, pool_seqs, ingested, shed
 
     def run(self, state, *, until: float | None = None,

@@ -116,6 +116,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.events import ARG_WIDTH, Event
+from repro.core.spans import ABSORB, MERGE
 
 _INF = jnp.float32(jnp.inf)
 _I32_MAX = jnp.int32(2**31 - 1)
@@ -1557,6 +1558,7 @@ def tiered3_queue_next_time(q: Tiered3DeviceQueue):
     return jnp.where(q.front_n > 0, q.f_times[0], rest)
 
 
+@jax.named_scope(MERGE)
 def _merge_runs_into_main(q: Tiered3DeviceQueue) -> Tiered3DeviceQueue:
     """Drain the whole run pool into the main ring (rare path).
 
@@ -1625,6 +1627,7 @@ def _merge_runs_into_main(q: Tiered3DeviceQueue) -> Tiered3DeviceQueue:
     )
 
 
+@jax.named_scope(MERGE)
 def _rotate_main(q: Tiered3DeviceQueue) -> Tiered3DeviceQueue:
     """Re-center the sorted main ring — one O(P) gather, no sort.
 
@@ -2291,6 +2294,7 @@ def tiered3_queue_fill_rows_tagged(q: Tiered3DeviceQueue, rows, seqs,
     )
 
 
+@jax.named_scope(ABSORB)
 def tiered3_queue_absorb_rows(q: Tiered3DeviceQueue, rows, seqs,
                               insert=None) -> Tiered3DeviceQueue:
     """Absorb out-of-band rows carrying externally assigned seqs.

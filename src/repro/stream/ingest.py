@@ -36,6 +36,7 @@ import numpy as np
 
 import jax
 
+from repro.core.spans import FEEDER_STAGE
 from repro.stream.source import EMIT_WIDTH, ArrivalSource
 
 _I32_MAX = 2**31 - 1
@@ -131,10 +132,11 @@ class StreamFeeder:
         return blk
 
     def _next_block_sync(self):
-        rows = next(self._gen, None)
-        if rows is None:
-            return None
-        blk = self._make_block(self._c0_next, rows)
+        with jax.profiler.TraceAnnotation(FEEDER_STAGE):
+            rows = next(self._gen, None)
+            if rows is None:
+                return None
+            blk = self._make_block(self._c0_next, rows)
         self._c0_next += rows.shape[0]
         return blk
 
