@@ -96,9 +96,10 @@ Two families of operations are provided:
   - only when the run pool is exhausted do the runs merge into the
     main array, and the main ring carries ``num_runs × stage_cap``
     physical slack slots so that merge is usually a bounded tail
-    append — the O(capacity) rotate+merge compaction fires only when
-    the slack is gone, amortized over an entire pool of staged events
-    and never on the per-batch path.
+    append — otherwise the pool merges with the sorted ring in linear
+    time (a binary-search rank per pool element, then shift passes over
+    the ring; no sort of the ring), amortized over an entire pool of
+    staged events and never on the per-batch path.
 
   Same bit-exact contract and logical-capacity rule as the other
   families (``capacity`` excludes the slack; front/staging/runs are
@@ -1364,9 +1365,10 @@ class Tiered3DeviceQueue(NamedTuple):
       summary is ``r_times[i, r_off[i]]``.
     * ``m_*`` — the **main** head-offset ring, physically
       ``capacity + num_runs * stage_cap`` slots: the extra slack lets
-      an exhausted run pool usually merge into main as one bounded
-      tail append; the O(capacity) rotate+merge compaction only fires
-      once the slack itself is gone.
+      an exhausted run pool merge into main as one bounded tail append
+      when the whole pool lies past the main tail; otherwise the pool
+      and the ring merge in O(capacity) linear passes, with no sort
+      (:func:`_merge_block_into_ring`).
 
     Because every element's true ``seq`` participates in the run and
     refill merges, no eviction tags are needed: lexicographic
@@ -1558,6 +1560,89 @@ def tiered3_queue_next_time(q: Tiered3DeviceQueue):
     return jnp.where(q.front_n > 0, q.f_times[0], rest)
 
 
+def _ring_rank(q: Tiered3DeviceQueue, bt, bs):
+    """Lex rank of each ``(bt, bs)`` key among the ``main_n`` live main
+    elements: how many of them sort strictly before it.  A vectorised
+    binary search over the logical ring (physical slot
+    ``(m_head + i) % P``) — ``ceil(log2(capacity + 1))`` rounds, enough
+    for any ``main_n <= capacity``, of one small gather per column."""
+    P = q.main_phys
+    lo = jnp.zeros(bt.shape, jnp.int32)
+    hi = jnp.full(bt.shape, q.main_n, jnp.int32)
+    for _ in range(q.capacity.bit_length()):
+        mid = (lo + hi) // 2
+        slot = (q.m_head + mid) % P
+        mt = q.m_times[slot]
+        ms = q.m_seqs[slot]
+        before = (mt < bt) | ((mt == bt) & (ms < bs))
+        active = lo < hi
+        lo = jnp.where(active & before, mid + 1, lo)
+        hi = jnp.where(active & ~before, mid, hi)
+    return lo
+
+
+def _shift_down(col, d: int, fill):
+    """``col`` moved ``d`` slots toward the end (static ``d``), the
+    first ``d`` slots set to ``fill``; what passes the end is lost."""
+    pad = jnp.full((d,) + col.shape[1:], fill, col.dtype)
+    return jnp.concatenate([pad, col[:-d]])
+
+
+def _merge_block_into_ring(q: Tiered3DeviceQueue, run_live, bt, by, ba, bs):
+    """Linear-time merge of the ring's live window with a sorted block,
+    written at physical head 0 — exactly the ``(time, seq)`` sort of
+    ring ∪ block, with no sort and no ring-long gather.
+
+    Block element ``j`` (``j < run_live``) lands at ``j + rank_j``
+    (:func:`_ring_rank`); main element ``i`` at ``i + c_i``, where
+    ``c_i`` counts the block elements before it.  The ring is unrolled
+    by one ``dynamic_slice`` of the doubled column, each main element
+    moves forward by ``c_i`` in ``ceil(log2(RL + 1))`` static
+    shift-and-select passes, highest bit first, and the block rows go
+    into the holes with one ``RL``-row scatter.  After the passes for
+    the bits above ``k`` an element sits at ``i + (c_i`` with bits below
+    ``k`` cleared); that is strictly increasing in ``i`` because ``c`` is
+    non-decreasing, so no pass ever moves two elements onto one slot.
+    """
+    P = q.main_phys
+    RL = bt.shape[0]
+    j_idx = jnp.arange(RL, dtype=jnp.int32)
+    rank = _ring_rank(q, bt, bs)
+    b_live = j_idx < run_live
+    # rank <= main_n <= capacity < P, so no live rank is dropped.
+    counts = jnp.zeros((P,), jnp.int32).at[
+        jnp.where(b_live, rank, P)].add(1, mode="drop")
+    shift = jnp.cumsum(counts)
+
+    def rows(mask, col):
+        return mask if col.ndim == 1 else mask[:, None]
+
+    # The live window moved to physical 0: slot i holds logical i.
+    occ = jnp.arange(P) < q.main_n
+    fills = (jnp.inf, -1, 0.0, 2**31 - 1)
+    cols = [
+        jnp.where(rows(occ, c), jax.lax.dynamic_slice_in_dim(
+            jnp.concatenate([c, c]), q.m_head, P, 0), f)
+        for c, f in zip((q.m_times, q.m_types, q.m_args, q.m_seqs), fills)
+    ]
+    for k in reversed(range(RL.bit_length())):
+        d = 1 << k
+        move = occ & ((shift & d) != 0)
+        land = _shift_down(move, d, False)
+        occ = land | (occ & ~move)
+        shift = jnp.where(land, _shift_down(shift, d, 0), shift)
+        cols = [jnp.where(rows(land, c), _shift_down(c, d, f), c)
+                for c, f in zip(cols, fills)]
+    # Dead rows get distinct slots past the end, which the scatter drops.
+    dest = jnp.where(b_live, j_idx + rank, P + j_idx)
+    mt, my, ma, ms = (
+        jnp.where(rows(occ, c), c, f).at[dest].set(
+            b, mode="drop", indices_are_sorted=True, unique_indices=True)
+        for c, f, b in zip(cols, fills, (bt, by, ba, bs)))
+    return q._replace(m_times=mt, m_types=my, m_args=ma, m_seqs=ms,
+                      m_head=jnp.int32(0))
+
+
 @jax.named_scope(MERGE)
 def _merge_runs_into_main(q: Tiered3DeviceQueue) -> Tiered3DeviceQueue:
     """Drain the whole run pool into the main ring (rare path).
@@ -1568,9 +1653,11 @@ def _merge_runs_into_main(q: Tiered3DeviceQueue) -> Tiered3DeviceQueue:
     minimum strictly exceeds the main tail and the ring's physical
     slack still fits it, ONE tail ``dynamic_update_slice`` lands it.
     Fallback (the only O(capacity) operation in the tiered3 family):
-    rotate the ring back to physical 0 and lex-merge — amortized over
-    an entire pool (``num_runs × stage_cap`` staged events) per firing.
-    Never drops: occupancy <= logical capacity <= physical size.
+    merge the sorted ring and the sorted block in linear time into a
+    ring at physical head 0 (:func:`_merge_block_into_ring`) —
+    amortized over an entire pool (``num_runs × stage_cap`` staged
+    events) per firing.  Never drops: occupancy <= logical capacity <=
+    physical size.
     """
     R, S, P = q.num_runs, q.stage_cap, q.main_phys
     RL = R * S
@@ -1602,24 +1689,9 @@ def _merge_runs_into_main(q: Tiered3DeviceQueue) -> Tiered3DeviceQueue:
             m_head=head,
         )
 
-    def merge_all(q):
-        ct = jnp.concatenate(
-            [_ring_unroll(q.m_times, jnp.inf, q.m_head, q.main_n), bt])
-        cy = jnp.concatenate(
-            [_ring_unroll(q.m_types, -1, q.m_head, q.main_n), by])
-        ca = jnp.concatenate(
-            [_ring_unroll(q.m_args, 0.0, q.m_head, q.main_n), ba])
-        cs = jnp.concatenate(
-            [_ring_unroll(q.m_seqs, 2**31 - 1, q.m_head, q.main_n), bs])
-        # Real elements <= logical capacity <= P, so truncating the
-        # sorted concat to P drops only sentinels.
-        perm = _lex_order(ct, cs)[:P]
-        return q._replace(
-            m_times=ct[perm], m_types=cy[perm], m_args=ca[perm],
-            m_seqs=cs[perm], m_head=jnp.int32(0),
-        )
-
-    q = jax.lax.cond(can_append, append, merge_all, q)
+    q = jax.lax.cond(
+        can_append, append,
+        lambda q: _merge_block_into_ring(q, run_live, bt, by, ba, bs), q)
     return q._replace(
         main_n=q.main_n + run_live,
         r_off=jnp.zeros((R,), jnp.int32),

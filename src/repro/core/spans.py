@@ -16,7 +16,7 @@ a trace by these names.
 EXTRACT = "des.extract"    # window extraction, front refill included
 DISPATCH = "des.dispatch"  # the composed batch: switch or entity run path
 INSERT = "des.insert"      # the emit insert (and its spill diversion)
-MERGE = "des.merge"        # the O(capacity) paths over the main ring
+MERGE = "des.merge"        # pool merge and rotate: linear passes over main
 ABSORB = "des.absorb"      # arrival and spill reabsorb; also a host span
 
 # -- host spans of the segment loop (``CompiledSim._segment_loop``) -----

@@ -3,8 +3,8 @@ device-queue ops vs the seed per-event reference ops.
 
 The tiered3 ops must reproduce the reference ``(time, seq)`` pop order
 BIT-EXACTLY — including timestamp ties, run-pool exhaustion (the merge
-into main, both the slack-append fast path and the rotate+merge
-compaction), bounded k-way refills that consume from several runs at
+into main, both the slack-append fast path and the linear merge at
+head 0), bounded k-way refills that consume from several runs at
 once, and overflow ghosts landing across all four tiers.  The
 stationary >=90%-occupancy property test drives exactly the
 near-head/far-future re-emit shape that made the two-tier flush merge
@@ -20,6 +20,7 @@ from _hypothesis_compat import given, settings, st
 from repro.core import DeviceEngine, EventRegistry, emits_events
 from repro.core.events import ARG_WIDTH
 from repro.core.queue import (
+    _merge_runs_into_main,
     device_queue_extract_ref,
     device_queue_from_host,
     device_queue_init,
@@ -239,6 +240,149 @@ def test_run_pool_exhaustion_merges_into_main():
         assert_t3_equals_flat(qa, qb, f"pool step {step}")
     # the stream above overflows the 2-run pool many times over
     assert int(qa.size) >= 0
+
+
+# ---------------------------------------------------------------------------
+# The run pool's merge into the main ring, called directly and held to a
+# NumPy lexsort oracle over ring + pool: every physical slot of all four
+# main columns, m_head and main_n, on both legs (slack append, linear
+# merge at head 0), with stale values in the ring's dead slots.
+# ---------------------------------------------------------------------------
+
+_merge_runs = jax.jit(_merge_runs_into_main)
+_MAIN_COLS = ("m_times", "m_types", "m_args", "m_seqs")
+_SENTINELS = (np.inf, -1, 0.0, 2**31 - 1)
+
+
+def _merge_case(seed, capacity, stage_cap, num_runs, main_n, m_head,
+                r_off, r_len, main_t, pool_t):
+    """A tiered3 queue whose ring holds ``main_n`` sorted elements from
+    ``m_head`` (wrapping past the physical end) and whose runs hold
+    sorted remainders ``[r_off, r_len)``; times drawn by ``main_t`` /
+    ``pool_t`` (rng, n) and unique seqs, so keys never tie."""
+    rng = np.random.default_rng(seed)
+    q = tiered3_queue_init(capacity, front_cap=4, stage_cap=stage_cap,
+                           num_runs=num_runs)
+    P = q.main_phys
+    seqs = rng.permutation(1000).astype(np.int32)
+    mt = np.asarray(main_t(rng, main_n), np.float32)
+    ms = seqs[:main_n]
+    order = np.lexsort((ms, mt))
+    # Stale values everywhere the ring holds no live element.
+    cols = {
+        "m_times": rng.uniform(-9, 99, P).astype(np.float32),
+        "m_types": np.full(P, 5, np.int32),
+        "m_args": np.full((P, ARG_WIDTH), 7.0, np.float32),
+        "m_seqs": rng.integers(0, 1000, P).astype(np.int32),
+    }
+    slot = (m_head + np.arange(main_n)) % P
+    cols["m_times"][slot] = mt[order]
+    cols["m_seqs"][slot] = ms[order]
+    cols["m_types"][slot] = rng.integers(0, 4, main_n)
+    cols["m_args"][slot] = rng.random((main_n, ARG_WIDTH))
+    runs = {
+        "r_times": np.full((num_runs, stage_cap), np.inf, np.float32),
+        "r_types": np.full((num_runs, stage_cap), -1, np.int32),
+        "r_args": np.zeros((num_runs, stage_cap, ARG_WIDTH), np.float32),
+        "r_seqs": np.full((num_runs, stage_cap), 2**31 - 1, np.int32),
+    }
+    k = main_n
+    for r, n in enumerate(r_len):
+        t = np.asarray(pool_t(rng, n), np.float32)
+        s = seqs[k:k + n]
+        k += n
+        o = np.lexsort((s, t))
+        runs["r_times"][r, :n] = t[o]
+        runs["r_seqs"][r, :n] = s[o]
+        runs["r_types"][r, :n] = rng.integers(0, 4, n)
+        runs["r_args"][r, :n] = rng.random((n, ARG_WIDTH))
+    return q._replace(
+        **{f: jnp.asarray(v) for f, v in {**cols, **runs}.items()},
+        m_head=jnp.int32(m_head), main_n=jnp.int32(main_n),
+        r_off=jnp.asarray(r_off, jnp.int32),
+        r_len=jnp.asarray(r_len, jnp.int32))
+
+
+def _merge_oracle(q):
+    """Expected main columns, m_head and main_n after the pool merge."""
+    P, S = q.main_phys, q.stage_cap
+    RL = q.num_runs * S
+    cols = [np.asarray(getattr(q, f)).copy() for f in _MAIN_COLS]
+    n, h = int(q.main_n), int(q.m_head)
+    live = ((np.arange(S)[None, :] >= np.asarray(q.r_off)[:, None])
+            & (np.arange(S)[None, :] < np.asarray(q.r_len)[:, None]))
+    pool = [np.asarray(getattr(q, "r" + f[1:]))[live] for f in _MAIN_COLS]
+    order = np.lexsort((pool[3], pool[0]))
+    pool = [c[order] for c in pool]
+    L = len(order)
+    head = h if n > 0 else 0
+    first = pool[0][0] if L else np.inf
+    if head + n + RL <= P and (n == 0 or first > cols[0][head + n - 1]):
+        # Slack append: the sorted pool, sentinel-padded to RL rows.
+        for c, b, fill in zip(cols, pool, _SENTINELS):
+            c[head + n:head + n + RL] = fill
+            c[head + n:head + n + L] = b
+        return cols, head, n + L
+    ring = (h + np.arange(n)) % P
+    both = [np.concatenate([c[ring], b]) for c, b in zip(cols, pool)]
+    order = np.lexsort((both[3], both[0]))
+    out = []
+    for c, b, fill in zip(cols, both, _SENTINELS):
+        o = np.full_like(c, fill)
+        o[:n + L] = b[order]
+        out.append(o)
+    return out, 0, n + L
+
+
+def _uniform(lo, hi):
+    return lambda rng, n: rng.uniform(lo, hi, n)
+
+
+def _ints(hi):
+    return lambda rng, n: rng.integers(0, hi, n)
+
+
+# (capacity, stage_cap, num_runs): P = 38, RL = 6 and P = 44, RL = 15.
+_G38, _G44 = (32, 3, 2), (29, 5, 3)
+
+
+@pytest.mark.parametrize("geom,main_n,m_head,r_off,r_len,main_t,pool_t", [
+    # interleaved, ring wrapped past the physical end
+    (_G38, 25, 30, [0, 0], [3, 3], _uniform(0, 10), _uniform(0, 10)),
+    # empty ring (stale head): the pool lands at physical 0
+    (_G38, 0, 17, [0, 1], [3, 2], _uniform(0, 10), _uniform(0, 10)),
+    # the pool wholly before the ring's head
+    (_G38, 20, 5, [0, 0], [3, 3], _uniform(10, 20), _uniform(0, 5)),
+    # wholly after the tail with no slack left: the linear merge
+    (_G38, 20, 15, [0, 0], [3, 2], _uniform(0, 10), _uniform(20, 30)),
+    # wholly after the tail with slack: the tail append
+    (_G38, 20, 2, [0, 0], [3, 3], _uniform(0, 10), _uniform(20, 30)),
+    # equal times everywhere, broken by seq, ring wrapped
+    (_G38, 26, 20, [0, 0], [3, 3], _ints(3), _ints(3)),
+    # partly consumed runs
+    (_G38, 22, 9, [1, 2], [3, 3], _uniform(0, 10), _uniform(0, 10)),
+    # an empty pool leaves the ring's elements where they are
+    (_G38, 18, 30, [2, 3], [2, 3], _uniform(0, 10), _uniform(0, 10)),
+    # P and RL not powers of two: wrapped, consumed, tied
+    (_G44, 11, 40, [2, 0, 4], [5, 5, 5], _ints(4), _ints(4)),
+    (_G44, 18, 30, [0, 3, 1], [5, 4, 5], _uniform(0, 10), _uniform(0, 10)),
+    (_G44, 14, 3, [0, 0, 0], [5, 5, 5], _uniform(5, 10), _uniform(0, 6)),
+])
+@pytest.mark.parametrize("seed", [0, 1])
+def test_merge_runs_into_main_matches_lexsort(seed, geom, main_n, m_head,
+                                              r_off, r_len, main_t, pool_t):
+    capacity, stage_cap, num_runs = geom
+    q = _merge_case(seed, capacity, stage_cap, num_runs, main_n, m_head,
+                    r_off, r_len, main_t, pool_t)
+    want, want_head, want_n = _merge_oracle(q)
+    got = _merge_runs(q)
+    for f, w in zip(_MAIN_COLS, want):
+        np.testing.assert_array_equal(np.asarray(getattr(got, f)), w,
+                                      err_msg=f)
+    assert int(got.m_head) == want_head
+    assert int(got.main_n) == want_n
+    assert not np.asarray(got.r_off).any()
+    assert not np.asarray(got.r_len).any()
 
 
 # ---------------------------------------------------------------------------

@@ -13,12 +13,15 @@ only one process may load the TPU library at a time, and every test
 worker imports this file.
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.core.events import ARG_WIDTH
+from repro.core.queue import _merge_runs_into_main, tiered3_queue_init
 from repro.kernels.queue_front import front_merge, window_extract
 
 
@@ -108,3 +111,20 @@ def test_default_engine_compiles_for_v5e(one_chip):
         spec, jax.eval_shape(lambda: sim.engine.initial_queue(())))
     compiled = sim.engine.lower_run(state, queue).compile()
     assert compiled.memory_analysis().temp_size_in_bytes > 0
+
+
+def test_pool_merge_compiles_without_ring_sort_for_v5e(one_chip):
+    """The run pool's merge into the main ring at PHOLD's geometry
+    (capacity 2^20 + 2^16, 8 runs of 256): the only sort left in the
+    optimised HLO is the pool's own, far shorter than the ring."""
+    queue = jax.tree.map(
+        lambda x: _spec(one_chip, x.shape, x.dtype),
+        jax.eval_shape(lambda: tiered3_queue_init(
+            1_114_112, front_cap=256, stage_cap=256, num_runs=8)))
+    P = queue.m_times.shape[0]
+    hlo = jax.jit(_merge_runs_into_main).lower(queue).compile().as_text()
+    sorts = [line for line in hlo.splitlines() if " sort(" in line]
+    assert sorts, "the pool is no longer sorted: check this test's premise"
+    for line in sorts:
+        lengths = [int(d) for d in re.findall(r"\[(\d+)", line.split("=")[1])]
+        assert max(lengths) < P, line
