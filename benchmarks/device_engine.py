@@ -1229,10 +1229,10 @@ def fused_dispatch(quick: bool = False, repeats: int = 5):
                 eng.dispatch(c, s, ts, tys, args)[0]),
             "masked": _carried(
                 lambda s, c, ts, tys, args, n:
-                eng_m._dispatch_masked(s, ts, tys, args, n)[0]),
+                eng_m.dispatch(s, ts, tys, args, n)[0]),
             "fused": _carried(
                 lambda s, c, ts, tys, args, n:
-                eng_f._dispatch_fused(c, s, ts, tys, args, n)[0]),
+                eng_f.dispatch(c, s, ts, tys, args, n)[0]),
         }, (s0, code, ts, tys, args, length), 256)
 
         out[wl] = {
@@ -1241,7 +1241,7 @@ def fused_dispatch(quick: bool = False, repeats: int = 5):
             "hot_word": list(word),
             "hot_word_share": float(
                 base.word_counts[hot_code] / base.word_counts.sum()),
-            "num_hot_words": eng_f._dispatch_fused.num_hot,
+            "num_hot_words": eng_f.dispatch.num_hot,
             "num_batch_words": eng.codec.num_batches,
             "repeats": repeats,
             "per_batch_us": per_batch,
@@ -1299,7 +1299,7 @@ def fused_dispatch_static(quick: bool = False, repeats: int = 5):
         out[wl] = {
             "batches": profile.batches,
             "events": profile.events,
-            "num_hot_words": sims["static"].engine._dispatch_fused.num_hot,
+            "num_hot_words": sims["static"].engine.dispatch.num_hot,
             "static_hot_words": [list(w)
                                  for w in sims["static"].engine.hot_words],
             "hot_share": {m: share(s.engine.hot_words)

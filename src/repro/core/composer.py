@@ -35,9 +35,9 @@ On-device dispatch additionally comes in two specialized shapes
   straight-line "super-procedures" (no masks, no per-type legs —
   handlers inlined back-to-back exactly like the full switch's
   branches, so XLA fuses/DCEs across event boundaries), reached
-  through a bounded ``lax.switch`` over W+1 branches via a
-  code→slot lookup table; every other word falls back to the masked
-  path.  Compile cost is W-linear (guarded by
+  through a bounded ``lax.switch`` over W+1 branches, picked by
+  comparing the word's code with the W hot codes; every other word
+  falls back to the masked path.  Compile cost is W-linear (guarded by
   ``benchmarks/compile_times.py``), and because hot branches, full-
   switch branches, and the masked path all execute the identical
   handler sequence, all three modes are bit-identical
@@ -197,6 +197,23 @@ class LazyComposer(_ComposerBase):
 # On-device dispatchers (TPU-native runtime, DESIGN.md §2 and §7)
 # ---------------------------------------------------------------------------
 
+# The most dense batch words (Σ|Σ|^i, i = 1..max_len) the full switch is
+# built for.  Two uses: a device build that names no dispatch takes the
+# switch at or below it and the masked per-lane path above it (three
+# types at 16 lanes are 64,570,080 words, each a branch to trace), and
+# the engine carries its per-word histogram (``word_counts``,
+# i32[num_batches]) only at or below it, so the loop carry stays small.
+WORD_COUNT_LIMIT = 4096
+
+
+def default_dispatch_mode(num_types: int, max_len: int) -> str:
+    """The dispatch a device build takes when none is named:
+    ``"switch"`` while the dense word count is at most
+    :data:`WORD_COUNT_LIMIT`, else ``"masked"``."""
+    words = DenseCodec(num_types, max_len).num_batches
+    return "switch" if words <= WORD_COUNT_LIMIT else "masked"
+
+
 def _emit_layout(max_len: int, max_emit: int):
     """Shared on-device emit-block layout: ``emits`` is
     ``f32[max_len * max_emit, 2 + ARG_WIDTH]`` rows of
@@ -283,6 +300,14 @@ def build_switch_dispatcher(
     while the simulation main loop never leaves the device.
     """
     _require_dense(codec, "on-device dispatch")
+    if codec.num_batches > WORD_COUNT_LIMIT:
+        raise ValueError(
+            f"the full switch over {codec.num_types} types and "
+            f"{codec.max_len} lanes has {codec.num_batches:,} batch "
+            f"words, more than the {WORD_COUNT_LIMIT:,} it is built for; "
+            "use dispatch_mode='masked', or name no dispatch_mode and "
+            "the build picks it"
+        )
     if not registry.frozen:
         registry.freeze()
     max_len = codec.max_len
@@ -403,10 +428,11 @@ def build_fused_dispatcher(
     straight-line super-procedures (:func:`make_word_branch` — the same
     fused bodies the full switch uses, so XLA optimizes across event
     boundaries, the paper's §III scope win) and reached through a
-    bounded ``lax.switch`` over W+1 branches: an ``i32[num_batches]``
-    lookup table maps each Horner code to its hot slot, with slot W —
-    every non-hot word — falling back to the generic masked path
-    (:func:`build_masked_dispatcher`).
+    bounded ``lax.switch`` over W+1 branches: a Horner code equal to
+    the code of hot word ``i`` takes slot ``i``, and every other code
+    slot W, the generic masked path (:func:`build_masked_dispatcher`).
+    The lookup compares against the W hot codes, so it costs the same
+    at any word count.
 
     ``dispatch(code, state, ts, types, args, length) -> (state, emits)``.
     Compile cost is W-linear plus the constant masked fallback
@@ -414,8 +440,9 @@ def build_fused_dispatcher(
     bit-identical to both other modes for every window, hot or not.
 
     Attributes: ``hot_words`` (the deduplicated tuple actually baked
-    in), ``num_hot``, ``hot_slot_table`` (the numpy code→slot table;
-    slot ``num_hot`` = fallback), plus the shared layout attrs.
+    in), ``num_hot``, ``hot_codes`` (their Horner codes, slot order),
+    ``slot_of`` (code → slot; ``num_hot`` = fallback), plus the shared
+    layout attrs.
     """
     _require_dense(codec, "fused dispatch")
     if not registry.frozen:
@@ -460,19 +487,23 @@ def build_fused_dispatcher(
 
     branches = [make_hot(w) for w in hot] + [fallback_branch]
 
-    table = np.full((codec.num_batches,), len(hot), np.int32)
-    for slot, word in enumerate(hot):
-        table[codec.encode(list(word))] = slot
-    table_j = jnp.asarray(table)
+    num_hot = len(hot)
+    hot_codes = np.asarray([codec.encode(list(w)) for w in hot], np.int32)
+    codes_j = jnp.asarray(hot_codes)
+    slots_j = jnp.arange(num_hot, dtype=jnp.int32)
+
+    def slot_of(code):
+        return jnp.min(jnp.where(codes_j == code, slots_j, num_hot),
+                       initial=num_hot)
 
     def dispatch(code, state, ts, types, args, length):
-        slot = table_j[jnp.clip(code, 0, codec.num_batches - 1)]
-        return jax.lax.switch(slot, branches, state, ts, types, args,
-                              length)
+        return jax.lax.switch(slot_of(code), branches, state, ts, types,
+                              args, length)
 
     dispatch.hot_words = hot
-    dispatch.num_hot = len(hot)
-    dispatch.hot_slot_table = table
+    dispatch.num_hot = num_hot
+    dispatch.hot_codes = hot_codes
+    dispatch.slot_of = slot_of
     dispatch.num_batches = codec.num_batches
     dispatch.max_len = max_len
     dispatch.max_emit = max_emit

@@ -72,11 +72,14 @@ import numpy as np
 
 from repro.core.codec import DenseCodec, PaperCodec, make_codec
 from repro.core.composer import (
+    WORD_COUNT_LIMIT,
     EagerComposer,
     LazyComposer,
+    _emit_layout,
     build_fused_dispatcher,
     build_masked_dispatcher,
     build_switch_dispatcher,
+    default_dispatch_mode,
 )
 from repro.core.events import ARG_WIDTH, EventRegistry
 from repro.core.queue import (
@@ -187,11 +190,8 @@ class Simulator:
 # ---------------------------------------------------------------------------
 
 # Default hot-set width for dispatch_mode="fused" without declared
-# hot_words, and the num_batches ceiling for carrying the per-word
-# batch-count histogram in the run stats (beyond it the i32[num_batches]
-# carry would dominate the loop state for pathological alphabets).
+# hot_words.
 _DEFAULT_HOT_W = 32
-_WORD_COUNT_LIMIT = 4096
 
 
 @dataclasses.dataclass
@@ -228,10 +228,15 @@ class DeviceEngine:
     valid ranges.
 
     ``dispatch_mode`` selects how an extracted window reaches its
-    handlers (DESIGN.md §7; all three are bit-identical):
+    handlers (DESIGN.md §7; all three are bit-identical).  Left
+    ``None``, the word count picks it (:func:`~repro.core.composer.
+    default_dispatch_mode`): ``"switch"`` up to
+    :data:`~repro.core.composer.WORD_COUNT_LIMIT` words, else
+    ``"masked"``; ``self.dispatch_mode`` then holds the choice.
 
-    * ``"switch"`` (default) — one ``lax.switch`` over ALL composed
-      batch words; maximal cross-event scope, compile cost Σ Tᵏ.
+    * ``"switch"`` — one ``lax.switch`` over ALL composed batch words;
+      maximal cross-event scope, compile cost Σ Tᵏ.  Naming it for
+      more words than the limit raises at once.
     * ``"masked"`` — the generic per-lane masked path (per-handler
       scope; O(T·max_batch_len) compile, no cross-event optimization).
     * ``"fused"`` — two-level: the top-W *hot* words (``hot_words``,
@@ -269,7 +274,7 @@ class DeviceEngine:
     front_cap: int | None = None
     stage_cap: int | None = None
     num_runs: int | None = None
-    dispatch_mode: str = "switch"
+    dispatch_mode: str | None = None
     hot_words: Any = None
     queue_kernels: str = "xla"
     entity_handlers: Mapping[int, Callable] | None = None
@@ -295,6 +300,9 @@ class DeviceEngine:
                 f"unknown queue_mode {self.queue_mode!r}; expected "
                 "'tiered', 'tiered3', 'flat', or 'reference'"
             )
+        if self.dispatch_mode is None:
+            self.dispatch_mode = default_dispatch_mode(
+                len(self.registry), self.max_batch_len)
         if self.dispatch_mode not in ("switch", "masked", "fused"):
             raise ValueError(
                 f"unknown dispatch_mode {self.dispatch_mode!r}; expected "
@@ -354,20 +362,19 @@ class DeviceEngine:
             self.num_runs = 8
         self.num_runs = max(self.num_runs, 1)
         self.codec = DenseCodec(len(self.registry), self.max_batch_len)
-        # The full-enumeration switch is always available (it is the
-        # "switch"-mode path and the attribute contract benchmarks
-        # probe); building it only constructs Python closures — nothing
-        # is traced until a mode actually dispatches through it.
-        self.dispatch = build_switch_dispatcher(
-            self.registry, self.codec, max_emit=self.max_emit
-        )
-        self._dispatch_masked = None
-        self._dispatch_fused = None
-        if self.dispatch_mode == "masked":
-            self._dispatch_masked = build_masked_dispatcher(
+        self._empty_emits = _emit_layout(self.max_batch_len,
+                                         self.max_emit)[2]
+        # Only the chosen dispatcher is built: the full switch's
+        # branches number Σ Tᵏ.
+        if self.dispatch_mode == "switch":
+            self.dispatch = build_switch_dispatcher(
                 self.registry, self.codec, max_emit=self.max_emit
             )
-        elif self.dispatch_mode == "fused":
+        elif self.dispatch_mode == "masked":
+            self.dispatch = build_masked_dispatcher(
+                self.registry, self.codec, max_emit=self.max_emit
+            )
+        else:
             hot = self.hot_words
             if hot is None:
                 # No profile declared: bake the first W dense codes
@@ -381,15 +388,15 @@ class DeviceEngine:
                     for c in range(min(self.codec.num_batches,
                                        _DEFAULT_HOT_W))
                 ]
-            self._dispatch_fused = build_fused_dispatcher(
+            self.dispatch = build_fused_dispatcher(
                 self.registry, self.codec, hot, max_emit=self.max_emit
             )
-            self.hot_words = self._dispatch_fused.hot_words
+            self.hot_words = self.dispatch.hot_words
         # Per-word batch histogram in the run stats (hot-word profiling
-        # + benchmarks/batch_counts.py), gated so a pathological
-        # alphabet cannot blow up the while-loop carry.
+        # + benchmarks/batch_counts.py), gated so a large alphabet
+        # cannot blow up the while-loop carry.
         self._track_word_counts = (
-            self.codec.num_batches <= _WORD_COUNT_LIMIT
+            self.codec.num_batches <= WORD_COUNT_LIMIT
         )
         self._lookaheads = self.registry.lookaheads()
         if self.entity_handlers:
@@ -433,7 +440,7 @@ class DeviceEngine:
                      front_cap: int | None = None,
                      stage_cap: int | None = None,
                      num_runs: int | None = None,
-                     dispatch_mode: str = "switch",
+                     dispatch_mode: str | None = None,
                      hot_words=None,
                      queue_kernels: str = "xla",
                      validate: str = "off",
@@ -553,12 +560,10 @@ class DeviceEngine:
         """
         def switch_path(state):
             if self.dispatch_mode == "masked":
-                return self._dispatch_masked(state, ts, tys, args, length)
+                return self.dispatch(state, ts, tys, args, length)
             code = self.codec.encode_jnp(tys, length)
             if self.dispatch_mode == "fused":
-                return self._dispatch_fused(
-                    code, state, ts, tys, args, length
-                )
+                return self.dispatch(code, state, ts, tys, args, length)
             return self.dispatch(code, state, ts, tys, args)
 
         if not self._run_branches:
@@ -581,7 +586,7 @@ class DeviceEngine:
                 jnp.maximum(branch, 0), self._run_branches,
                 state, ts, args, entity_ids, in_window,
             )
-            return state, self.dispatch.empty_emits()
+            return state, self._empty_emits()
 
         return jax.lax.cond(is_run, run_path, switch_path, state)
 
@@ -600,6 +605,7 @@ class DeviceEngine:
             "events": jnp.int32(0),
             "emitted": jnp.int32(0),
             "time": jnp.float32(0.0),
+            "type_counts": jnp.zeros((len(self.registry),), jnp.int32),
         }
         if self._track_word_counts:
             stats["word_counts"] = jnp.zeros(
@@ -614,13 +620,27 @@ class DeviceEngine:
             # copy/fusion kernel per super-step on CPU.
             stats["fault_word"] = jnp.int32(0)
         if self.overflow == "spill":
-            rows = self.dispatch.empty_emits()
+            rows = self._empty_emits()
             stats["spill_rows"] = jnp.asarray(rows)
             stats["spill_seqs"] = jnp.zeros((rows.shape[0],), jnp.int32)
             stats["spill_n"] = jnp.int32(0)
             stats["bound_t"] = jnp.float32(jnp.inf)
             stats["bound_seq"] = jnp.int32(2**31 - 1)
         return stats
+
+    def _count_window(self, stats, new_stats, tys, length):
+        """Add one window to the histograms the stats carry:
+        ``type_counts`` (events by type, every build) and
+        ``word_counts`` (batches by Horner word, while the code space
+        is at most ``WORD_COUNT_LIMIT``; XLA CSEs the encode against
+        the switch path's)."""
+        lane = jnp.arange(self.max_batch_len) < length
+        hit = (tys[:, None] == jnp.arange(len(self.registry))) & lane[:, None]
+        new_stats["type_counts"] = (
+            stats["type_counts"] + jnp.sum(hit, axis=0, dtype=jnp.int32))
+        if self._track_word_counts:
+            code = self.codec.encode_jnp(tys, length)
+            new_stats["word_counts"] = stats["word_counts"].at[code].add(1)
 
     def place_queue(self, queue):
         """Re-place a restored queue's leaves for this engine.  The
@@ -819,11 +839,7 @@ class DeviceEngine:
                 + jnp.sum(emits[:, 1] >= 0).astype(jnp.int32),
                 "time": jnp.maximum(stats["time"], last_t),
             }
-            if self._track_word_counts:
-                # Per-word histogram (XLA CSEs the encode against the
-                # dispatch path's — same pure computation).
-                code = self.codec.encode_jnp(tys, length)
-                new_stats["word_counts"] = stats["word_counts"].at[code].add(1)
+            self._count_window(stats, new_stats, tys, length)
             if spill:
                 new_stats.update(spill_delta)
             elif fenced:
@@ -858,9 +874,10 @@ class DeviceEngine:
         counters — ``max_batches`` then caps the TOTAL batch count, not
         this call's increment.
 
-        Stats carry ``word_counts`` (i32[num_batches], batches per
-        Horner word — the fused-dispatch profiling source) whenever the
-        code space is small enough to track, plus the fault word /
+        Stats carry ``type_counts`` (i32[T], events per type) and
+        ``word_counts`` (i32[num_batches], batches per Horner word — the
+        fused-dispatch profiling source) whenever the code space is
+        small enough to track, plus the fault word /
         spill buffer when ``validate`` / ``overflow='spill'`` enable
         them.
 

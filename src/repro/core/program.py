@@ -219,7 +219,8 @@ class RunResult:
     ``c`` — the observable profiling source for
     ``build(..., dispatch_mode="fused", hot_words=...)`` hot-word
     selection (see :func:`repro.core.composer.hot_words_from_counts`);
-    ``None`` on host backends.
+    ``None`` on host backends.  ``type_counts`` (device backends, any
+    code space) is the number of executed events of each type id.
 
     ``emitted``/``pending``/``spilled`` (device backends) complete the
     conservation law ``seeded + ingested + emitted == events + pending
@@ -245,6 +246,7 @@ class RunResult:
     rollbacks: int = 0
     raw: Any = None
     word_counts: Any = None
+    type_counts: Any = None
     emitted: int = 0
     pending: int = 0
     spilled: int = 0
@@ -488,7 +490,7 @@ class SimProgram:
               capacity: int | None = None,
               front_cap: int | None = None, stage_cap: int | None = None,
               num_runs: int | None = None,
-              dispatch_mode: str = "switch",
+              dispatch_mode: str | None = None,
               hot_words: Sequence | str | None = None,
               queue_kernels: str = "xla",
               validate: str = "off",
@@ -518,7 +520,8 @@ class SimProgram:
         dispatch path (``"switch"``: one switch over every composed
         word; ``"masked"``: the generic per-lane path; ``"fused"``:
         top-W hot-word super-procedures + masked fallback, DESIGN.md
-        §7) — all three bit-identical; ``hot_words`` declares the
+        §7) — all three bit-identical; left ``None``, the word count
+        picks ``"switch"`` up to 4,096 words and ``"masked"`` above; ``hot_words`` declares the
         fused hot set as sequences of type names or ids (default: the
         first 32 dense codes; profile a run's
         ``RunResult.word_counts`` for a real selection), or the string
@@ -664,7 +667,7 @@ class SimProgram:
                 "front_cap": front_cap is not None,
                 "stage_cap": stage_cap is not None,
                 "num_runs": num_runs is not None,
-                "dispatch_mode": dispatch_mode != "switch",
+                "dispatch_mode": dispatch_mode is not None,
                 "hot_words": hot_words is not None,
                 "queue_kernels": queue_kernels != "xla",
                 "validate": validate != "off",
@@ -1011,6 +1014,7 @@ class CompiledSim:
                 manager.wait()
 
         word_counts = stats.get("word_counts")
+        type_counts = stats.get("type_counts")
         raw = dict(stats)
         raw["final_queue"] = queue
         return RunResult(
@@ -1022,6 +1026,8 @@ class CompiledSim:
             raw=raw,
             word_counts=(None if word_counts is None
                          else np.asarray(word_counts)),
+            type_counts=(None if type_counts is None
+                         else np.asarray(type_counts)),
             emitted=int(np.asarray(stats.get("emitted", 0))),
             pending=int(np.asarray(eng.queue_occupancy(queue))),
             spilled=int(pool_seqs.size),

@@ -351,7 +351,7 @@ class ShardedDeviceEngine(DeviceEngine):
                      front_cap: int | None = None,
                      stage_cap: int | None = None,
                      num_runs: int | None = None,
-                     dispatch_mode: str = "switch",
+                     dispatch_mode: str | None = None,
                      hot_words=None,
                      queue_kernels: str = "xla",
                      validate: str = "off",
@@ -635,10 +635,7 @@ class ShardedDeviceEngine(DeviceEngine):
                 "emitted": stats["emitted"] + num_valid,
                 "time": jnp.maximum(stats["time"], last_t),
             }
-            if self._track_word_counts:
-                code = self.codec.encode_jnp(tys, length)
-                new_stats["word_counts"] = \
-                    stats["word_counts"].at[code].add(1)
+            self._count_window(stats, new_stats, tys, length)
             if fenced:
                 new_stats["bound_t"] = stats["bound_t"]
                 new_stats["bound_seq"] = stats["bound_seq"]
@@ -804,10 +801,7 @@ class ShardedDeviceEngine(DeviceEngine):
                 "emitted": stats["emitted"] + num_valid,
                 "time": jnp.maximum(stats["time"], last_t),
             }
-            if self._track_word_counts:
-                code = self.codec.encode_jnp(tys, length)
-                new_stats["word_counts"] = \
-                    stats["word_counts"].at[code].add(1)
+            self._count_window(stats, new_stats, tys, length)
             if fenced:
                 new_stats["bound_t"] = stats["bound_t"]
                 new_stats["bound_seq"] = stats["bound_seq"]

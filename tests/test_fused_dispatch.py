@@ -98,14 +98,14 @@ def test_hot_slot_table():
     fu = build_fused_dispatcher(reg, codec, hot, max_emit=1)
     assert fu.hot_words == ((1,), (0, 1))
     assert fu.num_hot == 2
-    table = np.asarray(fu.hot_slot_table)
-    assert table.shape == (codec.num_batches,)
+    assert fu.hot_codes.tolist() == [codec.encode(list(w)) for w in hot]
     for code in range(codec.num_batches):
         word = tuple(codec.decode(code))
+        slot = int(fu.slot_of(jnp.int32(code)))
         if word in hot:
-            assert table[code] == hot.index(word)
+            assert slot == hot.index(word)
         else:
-            assert table[code] == len(hot)  # fallback slot
+            assert slot == len(hot)  # fallback slot
 
 
 def test_fused_validates_hot_words():
@@ -132,8 +132,9 @@ def test_default_hot_set_covers_small_alphabets():
     eng = sim.engine
     assert eng.dispatch_mode == "fused"
     assert len(eng.hot_words) == eng.codec.num_batches
-    table = np.asarray(eng._dispatch_fused.hot_slot_table)
-    assert (table < len(eng.hot_words)).all()
+    slots = [int(eng.dispatch.slot_of(jnp.int32(c)))
+             for c in range(eng.codec.num_batches)]
+    assert max(slots) < len(eng.hot_words)
 
 
 def test_word_counts_match_batches_and_composition():
