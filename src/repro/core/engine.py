@@ -64,7 +64,7 @@ On-device emit convention: handlers marked with ``@emits_events`` return
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Callable, Mapping
+from typing import Any, Callable, Mapping, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -121,6 +121,16 @@ from repro.core.scheduler import (
     run_unbatched,
 )
 from repro.core.vectorize import make_masked_run_handler
+
+
+class BoundaryRead(NamedTuple):
+    """What the segment driver reads of the queue and the run stats at
+    a segment boundary (:meth:`DeviceEngine.boundary_read`)."""
+
+    batches: int       # cumulative super-steps in the stats
+    spill_n: int       # rows in the stats' spill buffer (0 unbuilt)
+    occupancy: int     # real pending events in the queue
+    next_time: float   # earliest pending timestamp (inf when empty)
 
 
 class Simulator:
@@ -656,6 +666,45 @@ class DeviceEngine:
         if self.queue_mode == "tiered":
             return tiered_queue_occupancy(queue)
         return jnp.sum(queue.types >= 0).astype(jnp.int32)
+
+    def queue_next_time(self, queue):
+        """Earliest pending timestamp (``inf`` when empty)."""
+        if self.queue_mode == "tiered3":
+            return tiered3_queue_next_time(queue)
+        if self.queue_mode == "tiered":
+            return tiered_queue_next_time(queue)
+        if self.queue_mode == "flat":
+            return device_queue_next_time(queue)
+        return device_queue_next_time_ref(queue)
+
+    def _boundary_read(self, queue, counters):
+        # One i32[4] row, so the host pulls a single buffer; the time
+        # rides bitcast.
+        return jnp.stack([
+            counters["batches"],
+            counters.get("spill_n", jnp.int32(0)),
+            self.queue_occupancy(queue),
+            jax.lax.bitcast_convert_type(
+                self.queue_next_time(queue), jnp.int32),
+        ])
+
+    def boundary_read(self, queue, stats=None) -> BoundaryRead:
+        """Batch count, spill count, occupancy and next time in ONE
+        compiled call and one device-to-host transfer.
+
+        The segment driver needs all four at every boundary; computed
+        eagerly they are ~30 small ops, each its own dispatch, and four
+        read-backs.  ``stats=None`` reads the initial stats' counters.
+        Jitted once per engine and cached, like the entry audit."""
+        fn = self.__dict__.get("_boundary_read_jit")
+        if fn is None:
+            fn = jax.jit(self._boundary_read)
+            self._boundary_read_jit = fn
+        src = self.initial_run_stats() if stats is None else stats
+        counters = {k: src[k] for k in ("batches", "spill_n") if k in src}
+        row = np.asarray(fn(queue, counters))
+        return BoundaryRead(int(row[0]), int(row[1]), int(row[2]),
+                            float(row[3:].view(np.float32)[0]))
 
     def absorb_rows(self, queue, rows, seqs, insert):
         """Absorb externally keyed rows (stream arrivals) where

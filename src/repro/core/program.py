@@ -236,6 +236,12 @@ class RunResult:
     under ``backpressure="shed"``), which balances the law's right side
     exactly like ``dropped`` does for emits.  Both are 0 for closed
     runs on every backend.
+
+    ``boundaries`` (device backends) counts the segment boundaries the
+    driver ran: one per ``engine.run`` call, each followed by one
+    compiled read of the queue and the stats
+    (:meth:`~repro.core.engine.DeviceEngine.boundary_read`).  0 on host
+    backends.
     """
 
     state: Any
@@ -254,6 +260,7 @@ class RunResult:
     fault_step: int = -1
     ingested: int = 0
     shed: int = 0
+    boundaries: int = 0
 
     @property
     def mean_batch_length(self) -> float:
@@ -812,16 +819,16 @@ class CompiledSim:
         new_rows[:, 2:] = args[rest]
         return q, new_rows, seqs[rest].astype(np.int32)
 
-    def _absorb_spill(self, queue, pool_rows, pool_seqs, stats):
+    def _absorb_spill(self, queue, pool_rows, pool_seqs, stats, occ):
         """Reabsorb the host spill pool — wholesale when it fits,
         otherwise via the lex rebalance — and refresh the engine's
         execution fence to the lex-earliest key still outstanding.
+        ``occ`` is the queue's occupancy from the boundary read.
         Returns ``(queue, pool_rows, pool_seqs, stats)``."""
         from repro.core.queue import tiered3_queue_absorb_rows
 
         eng = self.engine
         if pool_seqs.size:
-            occ = int(np.asarray(eng.queue_occupancy(queue)))
             room = eng.capacity - occ
             if room >= int(pool_seqs.size):
                 queue = tiered3_queue_absorb_rows(
@@ -864,17 +871,6 @@ class CompiledSim:
             fn = jax.jit(absorb, donate_argnums=(0,))
             self._absorb_jit = fn
         return fn
-
-    def _queue_next_time(self, queue):
-        """Earliest pending timestamp (host float), single or sharded."""
-        from repro.core.queue import tiered3_queue_next_time
-
-        if hasattr(queue, "shards"):
-            return min(
-                float(np.asarray(tiered3_queue_next_time(q)))
-                for q in queue.shards
-            )
-        return float(np.asarray(tiered3_queue_next_time(queue)))
 
     @staticmethod
     def _save_checkpoint(manager, step, state, queue, stats,
@@ -996,7 +992,7 @@ class CompiledSim:
         idle_rounds = 0
         try:
             (state, queue, stats, pool_rows, pool_seqs,
-             ingested, shed) = self._segment_loop(
+             ingested, shed, seg_index, read) = self._segment_loop(
                 state, queue, stats, pool_rows, pool_seqs,
                 t_end=t_end, total_batches=total_batches, seg=seg,
                 spill=spill, manager=manager, segment_hook=segment_hook,
@@ -1029,12 +1025,13 @@ class CompiledSim:
             type_counts=(None if type_counts is None
                          else np.asarray(type_counts)),
             emitted=int(np.asarray(stats.get("emitted", 0))),
-            pending=int(np.asarray(eng.queue_occupancy(queue))),
+            pending=read.occupancy,
             spilled=int(pool_seqs.size),
             fault_word=int(np.asarray(stats.get("fault_word", 0))),
             fault_step=int(np.asarray(stats.get("fault_step", -1))),
             ingested=int(ingested),
             shed=int(shed),
+            boundaries=seg_index,
         )
 
     def _segment_loop(self, state, queue, stats, pool_rows, pool_seqs, *,
@@ -1053,15 +1050,21 @@ class CompiledSim:
         span = jax.profiler.TraceAnnotation
         # One ``des.boundary`` span per boundary: it opens after each
         # segment (and before the first) and closes as the next starts.
+        # ``read`` is the engine's one compiled read of the queue and
+        # the stats (batches, spill count, occupancy, next time); it is
+        # taken again whenever the queue changes outside a segment.
         with contextlib.ExitStack() as boundary:
             boundary.enter_context(span(spans.BOUNDARY))
+            read = eng.boundary_read(queue, stats)
             while True:
                 progressed = False
                 if spill and pool_seqs.size:
                     with span(spans.SPILL):
                         queue, pool_rows, pool_seqs, stats = \
                             self._absorb_spill(
-                                queue, pool_rows, pool_seqs, stats)
+                                queue, pool_rows, pool_seqs, stats,
+                                read.occupancy)
+                        read = eng.boundary_read(queue, stats)
                 # -- streamed admission: at most ONE arrival block per
                 # boundary, so the admitted/spilled/shed split is a pure
                 # function of the cursor, the horizon, and queue occupancy
@@ -1073,7 +1076,7 @@ class CompiledSim:
                     adm = feeder.admissible(t_end)
                     if adm:
                         with span(spans.OCCUPANCY):
-                            occ = int(np.asarray(eng.queue_occupancy(queue)))
+                            occ = read.occupancy
                         k = min(adm, max(eng.capacity - occ, 0))
                         if k > 0:
                             with span(spans.ABSORB, rows=k):
@@ -1103,9 +1106,7 @@ class CompiledSim:
                                 progressed = True
                             elif backpressure == "error":
                                 raise EngineFaultError(
-                                    FAULT_INGEST,
-                                    0 if stats is None
-                                    else int(np.asarray(stats["batches"])),
+                                    FAULT_INGEST, read.batches,
                                     detail=(
                                         f"{rest} arrival(s) found the "
                                         f"capacity-{eng.capacity} queue "
@@ -1135,8 +1136,7 @@ class CompiledSim:
                                 f_t, f_s = p_key
                         stats["bound_t"] = jnp.float32(f_t)
                         stats["bound_seq"] = jnp.int32(f_s)
-                done = (0 if stats is None
-                        else int(np.asarray(stats["batches"])))
+                done = read.batches
                 target = (total_batches if seg is None
                           else min(total_batches, done + seg))
                 boundary.close()
@@ -1145,13 +1145,14 @@ class CompiledSim:
                         state, queue, max_batches=target, t_end=t_end,
                         stats=stats,
                     )
-                    new_done = int(stats["batches"])
+                    read = eng.boundary_read(queue, stats)
+                    new_done = read.batches
                 boundary.enter_context(span(spans.BOUNDARY))
                 if new_done > done:
                     progressed = True
                 if spill:
                     with span(spans.SPILL):
-                        n = int(np.asarray(stats.get("spill_n", 0)))
+                        n = read.spill_n
                         if n > 0:
                             pool_rows = np.concatenate([
                                 pool_rows,
@@ -1182,13 +1183,14 @@ class CompiledSim:
                     out = segment_hook(seg_index, state, queue, stats)
                     if out is not None:
                         state, queue, stats = out
+                        read = eng.boundary_read(queue, stats)
                 if new_done >= total_batches:
                     break
                 pool_live = bool(spill and pool_seqs.size)
                 feeder_live = streamed and feeder.has_pending()
                 if pool_live or feeder_live:
                     with span(spans.NEXT_TIME):
-                        qt = self._queue_next_time(queue)
+                        qt = read.next_time
                         pool_t = (float(pool_rows[:, 0].min()) if pool_live
                                   else float("inf"))
                         feed_t = (feeder.next_time() if feeder_live
@@ -1222,7 +1224,8 @@ class CompiledSim:
                     # or admission fence with nothing outstanding — all
                     # terminal.
                     break
-        return state, queue, stats, pool_rows, pool_seqs, ingested, shed
+        return (state, queue, stats, pool_rows, pool_seqs, ingested, shed,
+                seg_index, read)
 
     def run(self, state, *, until: float | None = None,
             max_batches: int | None = None,

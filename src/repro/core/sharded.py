@@ -143,6 +143,7 @@ from repro.core.queue import (
     tiered3_queue_pop_prefix,
     tiered3_queue_to_flat,
     tiered3_stacked_absorb_rows,
+    tiered3_stacked_next_time,
     tiered3_stacked_occupancy,
     window_prefix_mask,
 )
@@ -447,6 +448,14 @@ class ShardedDeviceEngine(DeviceEngine):
             (tiered3_queue_occupancy(q) for q in queue.shards),
             jnp.int32(0),
         )
+
+    def queue_next_time(self, queue):
+        """Earliest pending timestamp across shards (``inf`` when
+        empty)."""
+        if isinstance(queue, StackedShardedQueue):
+            return jnp.min(tiered3_stacked_next_time(queue.q))
+        return jnp.min(jnp.stack(
+            [tiered3_queue_next_time(q) for q in queue.shards]))
 
     def _cheap_fault_bits(self, queue):
         if isinstance(queue, StackedShardedQueue):

@@ -20,11 +20,11 @@ MERGE = "des.merge"        # pool merge and rotate: linear passes over main
 ABSORB = "des.absorb"      # arrival and spill reabsorb; also a host span
 
 # -- host spans of the segment loop (``CompiledSim._segment_loop``) -----
-SEGMENT = "des.segment"      # one engine.run call and its batch count read
+SEGMENT = "des.segment"      # one engine.run call and the boundary read
 BOUNDARY = "des.boundary"    # the loop's work between two segments
-OCCUPANCY = "des.occupancy"  # host read of the queue's occupancy
+OCCUPANCY = "des.occupancy"  # admission room from the boundary read
 FENCE = "des.fence"          # admission-fence refresh (may wait for a block)
-NEXT_TIME = "des.next_time"  # host read of the earliest outstanding time
+NEXT_TIME = "des.next_time"  # earliest outstanding time vs the horizon
 SPILL = "des.spill"          # spill pool reabsorb and drain
 CHECKPOINT = "des.checkpoint"  # async checkpoint save
 
